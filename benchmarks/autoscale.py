@@ -54,9 +54,9 @@ from repro.core.api import STATION_ORDER
 from repro.core.sweep import model_for
 
 SMOKE = os.environ.get("BENCH_SMOKE", "") not in ("", "0")
-W_DIURNAL = 20 if SMOKE else 32
-N_STEPS = 3000 if SMOKE else 4800
-SEEDS = 2 if SMOKE else 3
+#: (diurnal windows, replay steps, seeds) of the full run
+FULL_SIZE = (32, 4800, 3)
+W_DIURNAL, N_STEPS, SEEDS = (20, 3000, 2) if SMOKE else FULL_SIZE
 
 # the deployment being autoscaled: a peak-provisioned compartmentalized
 # pipeline with every independently-scalable tier populated
@@ -66,9 +66,20 @@ CFG = {"variant": "compartmentalized", "f": 1, "n_proxy_leaders": 8,
 # floors keep the drained pipeline's latency floor (sum of per-server
 # demands) under the static peak p99 - the "equal p99" budget
 FLOORS = (("proxy", 3), ("replica", 2), ("batcher", 2), ("unbatcher", 2))
+# the diurnal policy grid (the frozen static-peak baseline is added by
+# autotune_policy)
+DIURNAL_POLICIES = (
+    AutoscalePolicy(target_low=0.4, target_high=0.65,
+                    cooldown_windows=0, min_counts=FLOORS),
+    AutoscalePolicy(target_low=0.35, target_high=0.6,
+                    cooldown_windows=0, min_counts=FLOORS),
+    AutoscalePolicy(target_low=0.4, target_high=0.65,
+                    cooldown_windows=0, min_counts=FLOORS,
+                    queue_high=1.0),
+)
 
 
-def _demand_row(cfg, w, alpha):
+def demand_row(cfg, w, alpha):
     m = model_for(dict(cfg), w)
     d_w, d_r, servers = m.demand_slots()
     k = len(STATION_ORDER)
@@ -81,21 +92,13 @@ def run(alpha=None):
     alpha = alpha if alpha is not None else calibrate_alpha()
     rows = []
     w = Workload(f_write=1.0)
-    base, srv = _demand_row(CFG, w, alpha)
+    base, srv = demand_row(CFG, w, alpha)
     rz = resizable_stations("compartmentalized", CFG)
     static_machines = int(srv.sum())
 
     # -- headline: diurnal policy search, autoscaled vs static-peak --------
     load = diurnal_load(W_DIURNAL, low=0.15, sharpness=2.0)
-    policies = (
-        AutoscalePolicy(target_low=0.4, target_high=0.65,
-                        cooldown_windows=0, min_counts=FLOORS),
-        AutoscalePolicy(target_low=0.35, target_high=0.6,
-                        cooldown_windows=0, min_counts=FLOORS),
-        AutoscalePolicy(target_low=0.4, target_high=0.65,
-                        cooldown_windows=0, min_counts=FLOORS,
-                        queue_high=1.0),
-    )
+    policies = DIURNAL_POLICIES
     t0 = time.perf_counter()
     tune = autotune_policy(policies, base, srv, load, p99_slack=1.0,
                            seeds=SEEDS, n_steps=N_STEPS,

@@ -27,6 +27,22 @@ from repro.core.api import Workload
 from repro.core.sweep import compile_models
 
 
+def fig28_models():
+    """The five Fig. 28 deployments: MultiPaxos, compartmentalized, the
+    unreplicated bound, then batched MultiPaxos and compartmentalized."""
+    return [
+        multipaxos_model(f=1),
+        compartmentalized_model(f=1, n_proxy_leaders=10, grid_rows=2,
+                                grid_cols=2, n_replicas=4),
+        unreplicated_model(),
+        compartmentalized_model(f=1, n_proxy_leaders=2, grid_rows=3,
+                                grid_cols=1, n_replicas=3, batch_size=100),
+        compartmentalized_model(f=1, n_proxy_leaders=3, grid_rows=2,
+                                grid_cols=2, n_replicas=2, batch_size=100,
+                                n_batchers=2, n_unbatchers=3),
+    ]
+
+
 def run(alpha=None):
     """``alpha`` overrides the table-derived anchor (headline numbers);
     the measured anchor (``calibrate_alpha(measured=True)``, read off an
@@ -37,18 +53,8 @@ def run(alpha=None):
     alpha_meas = calibrate_alpha(PAPER_MULTIPAXOS_UNBATCHED, measured=True)
     anchor_us = (time.perf_counter() - t0) * 1e6
     workload = Workload(name="write_only")  # Fig. 28 is the write-only mix
-    mp = multipaxos_model(f=1)
-    cmp_u = compartmentalized_model(f=1, n_proxy_leaders=10, grid_rows=2,
-                                    grid_cols=2, n_replicas=4)
-    unrep = unreplicated_model()
-    mp_b = compartmentalized_model(f=1, n_proxy_leaders=2, grid_rows=3,
-                                   grid_cols=1, n_replicas=3, batch_size=100)
-    cmp_b = compartmentalized_model(f=1, n_proxy_leaders=3, grid_rows=2,
-                                    grid_cols=2, n_replicas=2, batch_size=100,
-                                    n_batchers=2, n_unbatchers=3)
-
     t0 = time.perf_counter()
-    compiled = compile_models([mp, cmp_u, unrep, mp_b, cmp_b])
+    compiled = compile_models(fig28_models())
     _, xs, rs = compiled.mva(alpha, n_clients_max=512, workload=workload)
     sweep_us = (time.perf_counter() - t0) * 1e6
 

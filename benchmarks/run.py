@@ -30,6 +30,8 @@ import sys
 import time
 import traceback
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 from . import (
     ablation,
     autoscale,
@@ -163,6 +165,7 @@ def main(argv: list[str] | None = None) -> None:
                          f"choose from {[l for l, _ in MODULES]}")
         selected = [(l, m) for l, m in MODULES if l in wanted]
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for label, mod in selected:

@@ -21,18 +21,22 @@ KNOBS = dict(
 )
 
 
-def run():
-    alpha = calibrate_alpha(PAPER_MULTIPAXOS_UNBATCHED)
+def surface_grid():
+    """The 300-config surface: every KNOBS point, unbatched and batched."""
     # batch_size > 1 only makes sense with a batcher stage in front (the
     # factory amortizes downstream demand by B), so the batched half of the
     # grid carries batchers/unbatchers instead of crossing B with 0 batchers
     spec_unbatched = SweepSpec(**KNOBS)
     spec_batched = SweepSpec(**KNOBS, batch_sizes=(100,), n_batchers=(2,),
                              n_unbatchers=(3,))
-
-    t0 = time.perf_counter()
     configs = list(spec_unbatched.configs()) + list(spec_batched.configs())
-    compiled = compile_models([model_for(c) for c in configs], configs)
+    return compile_models([model_for(c) for c in configs], configs)
+
+
+def run():
+    alpha = calibrate_alpha(PAPER_MULTIPAXOS_UNBATCHED)
+    t0 = time.perf_counter()
+    compiled = surface_grid()
     compile_us = (time.perf_counter() - t0) * 1e6
 
     # peak surface: bottleneck law, vectorized over all configs

@@ -111,6 +111,7 @@ from .autoscale import (
 from .batched_execution import (
     BatchedExecutionResult,
     BatchedParityReport,
+    batched_parity,
     execute_configs,
     measured_capacity,
     run_variant_batched,
@@ -256,7 +257,7 @@ __all__ = [
     "ablation_steps", "as_f_write", "autoscale_grid", "autotune",
     "autotune_placement",
     "autotune_policy", "autotune_sharded",
-    "autotune_variants",
+    "autotune_variants", "batched_parity",
     "bottleneck_trace", "bpaxos_model", "build_schedule", "burst_events",
     "calibrate_alpha",
     "check_linearizable", "check_linearizable_partitioned",

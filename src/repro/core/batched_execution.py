@@ -66,7 +66,8 @@ from .transient import _quantile_from_hist
 from ..kernels.ops import latency_hist
 
 __all__ = [
-    "BatchedExecutionResult", "BatchedParityReport", "execute_configs",
+    "BatchedExecutionResult", "BatchedParityReport", "batched_parity",
+    "execute_configs",
     "measured_capacity", "run_variant_batched", "validate_batched",
 ]
 
@@ -560,14 +561,14 @@ def execute_configs(
             f"{done[tuple(short.T)].tolist()} of their op budgets in "
             f"{n_steps} steps - raise oversample margin or max_steps")
 
+    # histogram on the device, then pull the samples to the host once
     s = seeds_arr.size
-    lanes_lat = np.asarray(lat).reshape(m * s, -1)
-    lanes_fin = np.asarray(fin).reshape(m * s, -1).astype(np.float32)
-    lane_edges = np.repeat(edges, s, axis=0)
-    hist = np.asarray(latency_hist(jnp.asarray(lanes_lat),
-                                   jnp.asarray(lanes_fin),
-                                   jnp.asarray(lane_edges)))
+    hist = np.asarray(latency_hist(lat.reshape(m * s, -1),
+                                   fin.reshape(m * s, -1),
+                                   jnp.asarray(np.repeat(edges, s, axis=0))))
     hist = hist.reshape(m, s, n_bins)
+    lat = np.asarray(lat)
+    fin = np.asarray(fin)
     if geo is not None:
         # shift the (geometric) bin edges by each lane's deterministic WAN
         # offset AFTER binning: a sample in [e_k, e_k+1) is in
@@ -575,9 +576,9 @@ def execute_configs(
         # quantiles both read as total (wire + queueing) latency
         edges = edges + wan_off[:, None]
 
-    lat_np = np.asarray(lat, dtype=np.float64)
-    fin_np = np.asarray(fin)
-    lat_sum = np.where(fin_np, lat_np, 0.0).sum(axis=(2, 3))
+    # float64 sums, one config row at a time to bound host memory
+    lat_sum = np.stack([np.where(fin[i], lat[i].astype(np.float64), 0.0)
+                        .sum(axis=(1, 2)) for i in range(m)])
     t_last = np.asarray(t_last, dtype=np.float64)
 
     # completion-weighted blend of the probe-calibrated per-class costs:
@@ -709,15 +710,33 @@ def validate_batched(name: str,
     :func:`~repro.core.execution.validate_variant` analogue on the
     batched plane, with the same feedback loop: measured-parameter
     refinement comes off a real probe run of this very grid cell."""
-    spec = variant_spec(name)
-    if spec.executable is None:
-        raise ValueError(f"variant {name!r} declares no execution plane")
-    exe = spec.executable
     cfg = dict(config) if config is not None else default_config(name)
     cfg.setdefault("variant", name)
     w = resolve_workload(workload, where="validate_batched")
     res = run_variant_batched(name, cfg, w, n_commands=n_commands,
                               seeds=seeds, **kwargs)
+    return batched_parity(res, probe_n=n_commands,
+                          probe_seed=kwargs.get("probe_seed", 7919))
+
+
+def batched_parity(res: BatchedExecutionResult, config_index: int = 0,
+                   probe_n: Optional[int] = None,
+                   probe_seed: int = 7919) -> BatchedParityReport:
+    """Measured-vs-analytical msgs/cmd parity of one config of a batched
+    run (any row of a :meth:`CompiledSweep.execute` grid).  ``probe_n`` /
+    ``probe_seed`` size the feedback probe and should match the run's
+    calibration probe (``execute_configs`` defaults ``probe_n`` to
+    ``n_commands``)."""
+    rows_of = res.shard_lanes(config_index)
+    cfg = dict(res.configs[int(rows_of[0])])
+    cfg.setdefault("variant", "compartmentalized")
+    name = config_variant(cfg)
+    spec = variant_spec(name)
+    if spec.executable is None:
+        raise ValueError(f"variant {name!r} declares no execution plane")
+    exe = spec.executable
+    w = res.workload
+    n_commands = res.n_commands
 
     model_cfg = spec.adapt(cfg, w)
     if exe.model_feedback is not None:
@@ -727,22 +746,21 @@ def validate_batched(name: str,
         probe = run_variant(name, cfg,
                             replace(w, f_write=1.0) if exe.reads_as_writes
                             else w,
-                            n_commands=n_commands,
-                            seed=kwargs.get("probe_seed", 7919))
+                            n_commands=probe_n or n_commands,
+                            seed=probe_seed)
         model_cfg = exe.model_feedback(dict(model_cfg), probe)
     if res.geo is not None and res.lane_config is not None:
         # geo runs fan the config into region lanes; parity is against the
         # command-weighted aggregate (regions share the config's costs)
-        lanes = res.shard_lanes(0)
-        weights = res.lane_commands[lanes].astype(float)
-        nw = float(res.n_writes[lanes].sum())
-        agg = ((res.station_msgs[lanes] * weights[:, None]).sum(axis=0)
+        weights = res.lane_commands[rows_of].astype(float)
+        nw = float(res.n_writes[rows_of].sum())
+        agg = ((res.station_msgs[rows_of] * weights[:, None]).sum(axis=0)
                / max(weights.sum(), 1.0))
         measured = {STATION_ORDER[j]: float(v)
                     for j, v in enumerate(agg) if v > 0.0}
     else:
-        nw = float(res.n_writes[0])
-        measured = res.station_row(0)
+        nw = float(res.n_writes[rows_of[0]])
+        measured = res.station_row(int(rows_of[0]))
     realized = replace(w, f_write=nw / n_commands)
     predicted = spec.build(model_cfg).demands(realized)
 

@@ -20,10 +20,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.optim.compression import dequantize_int8, quantize_int8
-from repro.runtime.compat import shard_map
 
 
 def hierarchical_allreduce(x: jnp.ndarray, *, in_pod_axis: str = "data",

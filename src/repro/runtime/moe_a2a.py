@@ -33,10 +33,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.moe import MoEConfig
-from repro.runtime.compat import shard_map
 
 
 def _local_dispatch(x, top_w, top_i, n_experts: int, capacity: int):

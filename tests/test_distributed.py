@@ -30,7 +30,7 @@ def run_sub(body: str, n_devices: int = 4, timeout: int = 480) -> str:
 def test_hierarchical_allreduce_matches_psum():
     run_sub("""
     from jax.sharding import PartitionSpec as P
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
     from repro.runtime.collectives import hierarchical_allreduce
     mesh = jax.make_mesh((2, 2), ("pod", "data"))
     x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8)
@@ -50,7 +50,7 @@ def test_hierarchical_allreduce_matches_psum():
 def test_hierarchical_allreduce_compressed_close():
     run_sub("""
     from jax.sharding import PartitionSpec as P
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
     from repro.runtime.collectives import hierarchical_allreduce
     mesh = jax.make_mesh((2, 2), ("pod", "data"))
     key = jax.random.key(0)

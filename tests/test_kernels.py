@@ -227,6 +227,11 @@ HIST_SHAPES = [
     (1, 64, 8),
     (4, 128, 16),
     (3, 96, 24),   # N, B not powers of two
+    (9, 256, 16),  # lanes past one block of 8
+    (1, 640, 8),   # one lane, padded to a block of 8
+    (2, 5000, 8),  # several sample tiles, the last one ragged
+    (2, 300, 64),  # the execution plane's bin count
+    (2, 300, 100),  # bins and edges padded to 128
 ]
 
 
@@ -252,6 +257,21 @@ def test_latency_hist_kernel_matches_ref(shape):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
     # masked samples never land anywhere; every valid one lands somewhere
     assert int(out.sum()) == int(valid.sum())
+
+
+def test_latency_hist_kernel_edge_samples():
+    """Samples exactly on an edge, below the first, beyond the last and
+    at infinity bin as the reference bins them, across sample tiles."""
+    from repro.kernels.latency_hist import latency_hist
+
+    samples, valid, edges = _hist_inputs((3, 2600, 12), seed=4)
+    samples = (samples.at[:, 0].set(edges[:, 3]).at[:, 1].set(0.0)
+               .at[:, 2].set(1e9).at[:, 3].set(jnp.inf)
+               .at[:, 2048].set(edges[:, 0]).at[:, 2599].set(edges[:, -1]))
+    valid = valid.at[:, :4].set(1.0)
+    out = latency_hist(samples, valid, edges, interpret=True)
+    expect = ref.ref_latency_hist(samples, valid, edges)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 
 def test_latency_hist_matches_searchsorted_binning():

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .analytical import STATION_ORDER, calibrate_alpha
 from .api import Config, ShardingSpec, Workload, resolve_workload, variant_spec
 from .execution import StationParity, default_config, run_variant
@@ -88,12 +89,14 @@ def _probe_costs(name: str, cfg: Config, w: Workload, exe: Any,
     so read-path costs that only exist under concurrent writers (CRAQ's
     dirty-read forwarding) are captured at the mix they occur at."""
     k = len(STATION_ORDER)
+    tracing.count("repro.execute.probe_runs")
     t_w = run_variant(name, cfg, replace(w, f_write=1.0),
                       n_commands=probe_n, seed=probe_seed,
                       state_machine=state_machine)
     cost_w = np.asarray(t_w.demand_slots(), dtype=np.float64)[:k]
     if exe.reads_as_writes or w.f_write >= 1.0:
         return cost_w, cost_w.copy(), t_w
+    tracing.count("repro.execute.probe_runs")
     t_mix = run_variant(name, cfg, w, n_commands=probe_n,
                         seed=probe_seed + 1, state_machine=state_machine)
     mix = np.asarray(t_mix.demand_slots(), dtype=np.float64)[:k]
@@ -162,49 +165,58 @@ def _one_exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
         i, draw_i = xs
         t_end = (i + 1).astype(work.dtype) * dt
 
-        cls_cur = jnp.take_along_axis(
-            cls_stream, jnp.clip(op_i, 0, n_ops - 1)[:, None], axis=1)[:, 0]
-        # the head command's class picks each station's service demand
-        # (parked clients sit at stage == k and scatter out of bounds)
-        head_cls = (jnp.zeros((k,), jnp.int32)
-                    .at[stage].add(jnp.where(rank == 0, cls_cur, 0),
-                                   mode="drop"))
-        d_now = jnp.where(head_cls > 0, d_w, d_r)
-        # a zero demand for the head's class (a read at the leader) drains
-        # instantly - still one completion per step, like transient.py
-        rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
+        # the phases of transient._one_lane's step; the latency samples
+        # are binned after the scan (no "bin" phase here)
+        with jax.named_scope("execute.window"):
+            cls_cur = jnp.take_along_axis(
+                cls_stream, jnp.clip(op_i, 0, n_ops - 1)[:, None],
+                axis=1)[:, 0]
+            # the head command's class picks each station's service demand
+            # (parked clients sit at stage == k and scatter out of bounds)
+            head_cls = (jnp.zeros((k,), jnp.int32)
+                        .at[stage].add(jnp.where(rank == 0, cls_cur, 0),
+                                       mode="drop"))
+            d_now = jnp.where(head_cls > 0, d_w, d_r)
+            # a zero demand for the head's class (a read at the leader)
+            # drains instantly - still one completion per step, like
+            # transient.py
+            rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
 
-        busy = q > 0
-        work = jnp.where(busy, work - rate, work)
-        complete = busy & (work <= 0.0)                        # [K]
+        with jax.named_scope("execute.drain"):
+            busy = q > 0
+            work = jnp.where(busy, work - rate, work)
+            complete = busy & (work <= 0.0)                    # [K]
 
-        alive = stage < k
-        stage_c = jnp.clip(stage, 0, k - 1)
-        dep_here = alive & complete[stage_c]                   # [N]
-        moving = dep_here & (rank == 0)
-        fin = moving & finishes_at[stage_c]                    # op done
-        lat = t_end - enter_t
-        done_w = done_w + jnp.sum((fin & (cls_cur == 1)).astype(jnp.int32))
-        done_r = done_r + jnp.sum((fin & (cls_cur == 0)).astype(jnp.int32))
-        t_last = jnp.where(jnp.any(fin), t_end, t_last)
+            alive = stage < k
+            stage_c = jnp.clip(stage, 0, k - 1)
+            dep_here = alive & complete[stage_c]               # [N]
+            moving = dep_here & (rank == 0)
+            fin = moving & finishes_at[stage_c]                # op done
+            lat = t_end - enter_t
+            done_w = done_w + jnp.sum(
+                (fin & (cls_cur == 1)).astype(jnp.int32))
+            done_r = done_r + jnp.sum(
+                (fin & (cls_cur == 0)).astype(jnp.int32))
+            t_last = jnp.where(jnp.any(fin), t_end, t_last)
 
-        op_next = op_i + fin.astype(jnp.int32)
-        more = op_next < budget
-        enters = moving & (~fin | more)    # next hop, or next op; else park
-        dest = arrive_at[stage_c]
-        q_dep = q - complete.astype(q.dtype)
-        stage_new = jnp.where(moving, jnp.where(enters, dest, k), stage)
-        enter_new = jnp.where(fin, t_end, enter_t)
-        rank_new = jnp.where(
-            moving, q_dep[dest],
-            rank - (dep_here & (rank > 0)).astype(rank.dtype))
-        arrivals = (jnp.zeros_like(q)
-                    .at[jnp.where(enters, dest, k)]
-                    .add(1, mode="drop"))
-        q_new = q_dep + arrivals
-        fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
-        work_new = jnp.where(
-            fresh, draw_i + jnp.where(complete, work, 0.0), work)
+        with jax.named_scope("execute.route"):
+            op_next = op_i + fin.astype(jnp.int32)
+            more = op_next < budget
+            enters = moving & (~fin | more)  # next hop, or next op; or park
+            dest = arrive_at[stage_c]
+            q_dep = q - complete.astype(q.dtype)
+            stage_new = jnp.where(moving, jnp.where(enters, dest, k), stage)
+            enter_new = jnp.where(fin, t_end, enter_t)
+            rank_new = jnp.where(
+                moving, q_dep[dest],
+                rank - (dep_here & (rank > 0)).astype(rank.dtype))
+            arrivals = (jnp.zeros_like(q)
+                        .at[jnp.where(enters, dest, k)]
+                        .add(1, mode="drop"))
+            q_new = q_dep + arrivals
+            fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
+            work_new = jnp.where(
+                fresh, draw_i + jnp.where(complete, work, 0.0), work)
 
         return ((stage_new, rank_new, enter_new, op_next, q_new, work_new,
                  done_w, done_r, t_last), (fin, lat))
@@ -367,6 +379,7 @@ class BatchedExecutionResult:
                 f"p99 {self.latency_p99[m].mean():.2e}s")
 
 
+@tracing.span("repro.execute")
 def execute_configs(
     configs: Sequence[Config],
     workload: Optional[Union[Workload, float]] = None,
@@ -419,50 +432,52 @@ def execute_configs(
     uniform-geo lanes read today's numbers unchanged).  The queueing
     part stays measured; the WAN part is deterministic wire time the
     step engine has no wires for."""
-    if not configs:
-        raise ValueError("execute_configs: empty config list")
-    if geo is not None and sharding is not None:
-        raise ValueError(
-            "execute_configs: geo= and sharding= are mutually exclusive "
-            "(region lanes and shard lanes both multiply the row axis)")
-    w = resolve_workload(workload, where="execute_configs")
-    if isinstance(seeds, (int, np.integer)):
-        seeds_arr = np.arange(int(seeds), dtype=np.int32)
-    else:
-        seeds_arr = np.asarray(list(seeds), dtype=np.int32)
-    if seeds_arr.size == 0:
-        raise ValueError("execute_configs: need at least one seed")
-    n_probe = probe_n if probe_n is not None else n_commands
-    k = len(STATION_ORDER)
-    n_cfg = len(configs)
-    a = alpha if alpha is not None else calibrate_alpha()
+    with tracing.span("repro.execute.lower"):
+        if not configs:
+            raise ValueError("execute_configs: empty config list")
+        if geo is not None and sharding is not None:
+            raise ValueError(
+                "execute_configs: geo= and sharding= are mutually exclusive "
+                "(region lanes and shard lanes both multiply the row axis)")
+        w = resolve_workload(workload, where="execute_configs")
+        if isinstance(seeds, (int, np.integer)):
+            seeds_arr = np.arange(int(seeds), dtype=np.int32)
+        else:
+            seeds_arr = np.asarray(list(seeds), dtype=np.int32)
+        if seeds_arr.size == 0:
+            raise ValueError("execute_configs: need at least one seed")
+        n_probe = probe_n if probe_n is not None else n_commands
+        k = len(STATION_ORDER)
+        n_cfg = len(configs)
+        a = alpha if alpha is not None else calibrate_alpha()
 
-    sharded = sharding is not None and sharding.n_shards > 1
-    geoed = geo is not None and geo.n_regions > 1
-    n_sh = (sharding.n_shards if sharded
-            else geo.n_regions if geoed else 1)
-    if sharded:
-        lane_n = np.tile(split_counts(n_commands, shard_weights(sharding, w)),
-                         n_cfg).astype(np.int64)
-    elif geoed:
-        lane_n = np.tile(
-            split_counts(n_commands,
-                         np.asarray(geo.resolved_client_weights())),
-            n_cfg).astype(np.int64)
-    else:
-        lane_n = np.full((n_cfg,), n_commands, dtype=np.int64)
-    m = n_cfg * n_sh
-    lane_cfg = np.repeat(np.arange(n_cfg), n_sh)
-    lane_shard = np.tile(np.arange(n_sh), n_cfg)
+        sharded = sharding is not None and sharding.n_shards > 1
+        geoed = geo is not None and geo.n_regions > 1
+        n_sh = (sharding.n_shards if sharded
+                else geo.n_regions if geoed else 1)
+        if sharded:
+            lane_n = np.tile(
+                split_counts(n_commands, shard_weights(sharding, w)),
+                n_cfg).astype(np.int64)
+        elif geoed:
+            lane_n = np.tile(
+                split_counts(n_commands,
+                             np.asarray(geo.resolved_client_weights())),
+                n_cfg).astype(np.int64)
+        else:
+            lane_n = np.full((n_cfg,), n_commands, dtype=np.int64)
+        m = n_cfg * n_sh
+        lane_cfg = np.repeat(np.arange(n_cfg), n_sh)
+        lane_shard = np.tile(np.arange(n_sh), n_cfg)
 
-    wan_off = np.zeros((m,))
-    if geo is not None:
-        from .geo import wan_offsets
-        for i, raw in enumerate(configs):
-            cfg = dict(raw)
-            cfg.setdefault("variant", "compartmentalized")
-            off = wan_offsets(cfg, geo, workload=w, n_clients=n_clients)
-            wan_off[i * n_sh:(i + 1) * n_sh] = np.asarray(off)[:n_sh]
+        wan_off = np.zeros((m,))
+        if geo is not None:
+            from .geo import wan_offsets
+            for i, raw in enumerate(configs):
+                cfg = dict(raw)
+                cfg.setdefault("variant", "compartmentalized")
+                off = wan_offsets(cfg, geo, workload=w, n_clients=n_clients)
+                wan_off[i * n_sh:(i + 1) * n_sh] = np.asarray(off)[:n_sh]
 
     cost_w = np.zeros((n_cfg, k))
     cost_r = np.zeros((n_cfg, k))
@@ -478,81 +493,87 @@ def execute_configs(
             raise ValueError(
                 f"config {i}: variant {name!r} declares no execution plane")
         exe = spec.executable
-        cost_w[i], cost_r[i], _ = _probe_costs(
-            name, cfg, w, exe, n_probe, probe_seed, state_machine)
+        with tracing.span("repro.execute.probe"):
+            cost_w[i], cost_r[i], _ = _probe_costs(
+                name, cfg, w, exe, n_probe, probe_seed, state_machine)
         dw_row, dr_row, _ = spec.model(cfg, w).demand_slots()
         d_w_cfg[i, :len(dw_row)] = np.asarray(dw_row[:k]) / a
         d_r_cfg[i, :len(dr_row)] = np.asarray(dr_row[:k]) / a
         f_eff[i] = 1.0 if exe.reads_as_writes else w.f_write
 
-    # expand configs to lanes: shards of a config share its probe costs
-    # and per-command demands - a shard runs the full deployment, it just
-    # sees a fraction of the traffic
-    cost_w = np.repeat(cost_w, n_sh, axis=0)
-    cost_r = np.repeat(cost_r, n_sh, axis=0)
-    d_w = np.repeat(d_w_cfg, n_sh, axis=0)
-    d_r = np.repeat(d_r_cfg, n_sh, axis=0)
-    f_eff = np.repeat(f_eff, n_sh)
+    with tracing.span("repro.execute.streams"):
+        # expand configs to lanes: shards of a config share its probe costs
+        # and per-command demands - a shard runs the full deployment, it just
+        # sees a fraction of the traffic
+        cost_w = np.repeat(cost_w, n_sh, axis=0)
+        cost_r = np.repeat(cost_r, n_sh, axis=0)
+        d_w = np.repeat(d_w_cfg, n_sh, axis=0)
+        d_r = np.repeat(d_r_cfg, n_sh, axis=0)
+        f_eff = np.repeat(f_eff, n_sh)
 
-    cls_all: List[np.ndarray] = []
-    budget_all: List[np.ndarray] = []
-    n_writes = np.zeros((m,), dtype=np.int64)
-    for i in range(m):
-        cls, budget, n_w = _class_streams(int(lane_n[i]), f_eff[i],
-                                          n_clients, seeds_arr,
-                                          base_seed=probe_seed + i)
-        cls_all.append(cls)
-        budget_all.append(budget)
-        n_writes[i] = n_w
-    length = max(c.shape[2] for c in cls_all)
-    cls_all = [np.pad(c, ((0, 0), (0, 0), (0, length - c.shape[2])))
-               for c in cls_all]
+        cls_all: List[np.ndarray] = []
+        budget_all: List[np.ndarray] = []
+        n_writes = np.zeros((m,), dtype=np.int64)
+        for i in range(m):
+            cls, budget, n_w = _class_streams(int(lane_n[i]), f_eff[i],
+                                              n_clients, seeds_arr,
+                                              base_seed=probe_seed + i)
+            cls_all.append(cls)
+            budget_all.append(budget)
+            n_writes[i] = n_w
+        length = max(c.shape[2] for c in cls_all)
+        cls_all = [np.pad(c, ((0, 0), (0, 0), (0, length - c.shape[2])))
+                   for c in cls_all]
 
-    blend = f_eff[:, None] * d_w + (1.0 - f_eff[:, None]) * d_r
-    # station activity is a property of the *config's* mix, not of any one
-    # shard's integer split: a zero-command lane still routes through its
-    # config's active stations (and trivially drains nothing)
-    cfg_w = np.zeros((m,), dtype=bool)
-    cfg_r = np.zeros((m,), dtype=bool)
-    for i in range(n_cfg):
-        rows = slice(i * n_sh, (i + 1) * n_sh)
-        cfg_w[rows] = bool(n_writes[rows].sum() > 0)
-        cfg_r[rows] = bool(n_writes[rows].sum() < int(lane_n[rows].sum()))
-    active = ((cfg_w[:, None] & (d_w > 0))
-              | (cfg_r[:, None] & (d_r > 0)))               # [M, K]
-    entry, nxt = _routing(active)
-    dt = blend.max(axis=1) / oversample
-    if np.any(dt <= 0):
-        raise ValueError("a config row has zero effective demand")
+    with tracing.span("repro.execute.lower"):
+        blend = f_eff[:, None] * d_w + (1.0 - f_eff[:, None]) * d_r
+        # station activity is a property of the *config's* mix, not of any one
+        # shard's integer split: a zero-command lane still routes through its
+        # config's active stations (and trivially drains nothing)
+        cfg_w = np.zeros((m,), dtype=bool)
+        cfg_r = np.zeros((m,), dtype=bool)
+        for i in range(n_cfg):
+            rows = slice(i * n_sh, (i + 1) * n_sh)
+            cfg_w[rows] = bool(n_writes[rows].sum() > 0)
+            cfg_r[rows] = bool(n_writes[rows].sum() < int(lane_n[rows].sum()))
+        active = ((cfg_w[:, None] & (d_w > 0))
+                  | (cfg_r[:, None] & (d_r > 0)))               # [M, K]
+        entry, nxt = _routing(active)
+        dt = blend.max(axis=1) / oversample
+        if np.any(dt <= 0):
+            raise ValueError("a config row has zero effective demand")
 
-    # deterministic makespan bound: each station serves every command at
-    # most once, plus one step per (command, station) for instant drains
-    d_hot = np.where(active, np.maximum(d_w, d_r), 0.0)
-    span = (lane_n + n_clients) * d_hot.sum(axis=1)
-    steps = span / dt + (lane_n + n_clients) * active.sum(axis=1)
-    margin = 4.0 if exponential_service else 1.3
-    n_steps = int(math.ceil(margin * float(steps.max()))) + 8
-    n_steps = -(-n_steps // 256) * 256  # bucket: reuse the jit cache
-    if n_steps > max_steps:
-        raise ValueError(
-            f"execute_configs: bound of {n_steps} steps exceeds max_steps="
-            f"{max_steps}; raise max_steps or shrink the grid")
+        # deterministic makespan bound: each station serves every command at
+        # most once, plus one step per (command, station) for instant drains
+        d_hot = np.where(active, np.maximum(d_w, d_r), 0.0)
+        span = (lane_n + n_clients) * d_hot.sum(axis=1)
+        steps = span / dt + (lane_n + n_clients) * active.sum(axis=1)
+        margin = 4.0 if exponential_service else 1.3
+        n_steps = int(math.ceil(margin * float(steps.max()))) + 8
+        n_steps = -(-n_steps // 256) * 256  # bucket: reuse the jit cache
+        if n_steps > max_steps:
+            raise ValueError(
+                f"execute_configs: bound of {n_steps} steps exceeds max_steps="
+                f"{max_steps}; raise max_steps or shrink the grid")
 
-    rtt = np.maximum((blend * active).sum(axis=1), 1e-12)
-    lo = rtt * 0.5
-    hi = np.maximum(n_steps * dt, lo * 10.0)
-    ratio = (hi / lo) ** (1.0 / n_bins)
-    edges = lo[:, None] * ratio[:, None] ** np.arange(n_bins + 1)[None, :]
+        rtt = np.maximum((blend * active).sum(axis=1), 1e-12)
+        lo = rtt * 0.5
+        hi = np.maximum(n_steps * dt, lo * 10.0)
+        ratio = (hi / lo) ** (1.0 / n_bins)
+        edges = lo[:, None] * ratio[:, None] ** np.arange(n_bins + 1)[None, :]
 
-    fin, lat, done_w, done_r, t_last = _execute_batch(
-        jnp.asarray(d_w), jnp.asarray(d_r), jnp.asarray(entry),
-        jnp.asarray(nxt), jnp.asarray(np.stack(cls_all)),
-        jnp.asarray(np.stack(budget_all)), jnp.asarray(dt),
-        jnp.asarray(seeds_arr), n_clients=n_clients, n_steps=n_steps,
-        exponential=bool(exponential_service))
+    with tracing.span("repro.execute.dispatch"):
+        out = _execute_batch(
+            jnp.asarray(d_w), jnp.asarray(d_r), jnp.asarray(entry),
+            jnp.asarray(nxt), jnp.asarray(np.stack(cls_all)),
+            jnp.asarray(np.stack(budget_all)), jnp.asarray(dt),
+            jnp.asarray(seeds_arr), n_clients=n_clients, n_steps=n_steps,
+            exponential=bool(exponential_service))
+    fin, lat, done_w, done_r, t_last = out
+    tracing.wait("repro.execute.wait", done_w)
 
-    done_w = np.asarray(done_w, dtype=np.int64)
-    done_r = np.asarray(done_r, dtype=np.int64)
+    done_w, done_r = (x.astype(np.int64) for x in tracing.pull(
+        "repro.execute.pull", done_w, done_r))
     done = done_w + done_r
     if not np.all(done == lane_n[:, None]):
         short = np.argwhere(done != lane_n[:, None])
@@ -563,57 +584,60 @@ def execute_configs(
 
     # histogram on the device, then pull the samples to the host once
     s = seeds_arr.size
-    hist = np.asarray(latency_hist(lat.reshape(m * s, -1),
-                                   fin.reshape(m * s, -1),
-                                   jnp.asarray(np.repeat(edges, s, axis=0))))
-    hist = hist.reshape(m, s, n_bins)
-    lat = np.asarray(lat)
-    fin = np.asarray(fin)
-    if geo is not None:
-        # shift the (geometric) bin edges by each lane's deterministic WAN
-        # offset AFTER binning: a sample in [e_k, e_k+1) is in
-        # [e_k + wan, e_k+1 + wan) of the shifted edges, so histogram and
-        # quantiles both read as total (wire + queueing) latency
-        edges = edges + wan_off[:, None]
+    with tracing.span("repro.execute.hist"):
+        hist = latency_hist(lat.reshape(m * s, -1), fin.reshape(m * s, -1),
+                            jnp.asarray(np.repeat(edges, s, axis=0)))
+    tracing.wait("repro.execute.wait", hist)
+    hist, lat, fin, t_last = tracing.pull(
+        "repro.execute.pull", hist, lat, fin, t_last)
 
-    # float64 sums, one config row at a time to bound host memory
-    lat_sum = np.stack([np.where(fin[i], lat[i].astype(np.float64), 0.0)
-                        .sum(axis=(1, 2)) for i in range(m)])
-    t_last = np.asarray(t_last, dtype=np.float64)
+    with tracing.span("repro.execute.reduce"):
+        hist = hist.reshape(m, s, n_bins)
+        if geo is not None:
+            # shift the (geometric) bin edges by each lane's deterministic
+            # WAN offset AFTER binning: a sample in [e_k, e_k+1) is in
+            # [e_k + wan, e_k+1 + wan) of the shifted edges, so histogram
+            # and quantiles both read as total (wire + queueing) latency
+            edges = edges + wan_off[:, None]
 
-    # completion-weighted blend of the probe-calibrated per-class costs:
-    # the measured msgs/cmd surface (float64, so exact stations stay exact)
-    msgs = (done_w[:, 0, None] * cost_w + done_r[:, 0, None] * cost_r) \
-        / np.maximum(lane_n, 1)[:, None]
+        # float64 sums, one config row at a time to bound host memory
+        lat_sum = np.stack([np.where(fin[i], lat[i].astype(np.float64), 0.0)
+                            .sum(axis=(1, 2)) for i in range(m)])
+        t_last = t_last.astype(np.float64)
 
-    return BatchedExecutionResult(
-        configs=tuple(dict(configs[int(ci)]) for ci in lane_cfg),
-        workload=w,
-        n_commands=n_commands,
-        n_clients=n_clients,
-        seeds=seeds_arr,
-        station_msgs=msgs,
-        n_writes=done_w[:, 0].copy(),
-        cost_write=cost_w,
-        cost_read=cost_r,
-        throughput=lane_n[:, None] / np.maximum(t_last, 1e-30),
-        latency_mean=lat_sum / np.maximum(done, 1) + wan_off[:, None],
-        latency_p50=_quantile_from_hist(hist, edges, 0.50),
-        latency_p99=_quantile_from_hist(hist, edges, 0.99),
-        completed=done.astype(np.float64),
-        hist=hist,
-        bin_edges=edges,
-        dt=dt,
-        n_steps=n_steps,
-        alpha=a,
-        sharding=sharding if sharded else None,
-        lane_config=lane_cfg if (sharded or geoed) else None,
-        lane_shard=lane_shard if sharded else None,
-        lane_commands=lane_n if (sharded or geoed) else None,
-        geo=geo,
-        lane_region=lane_shard if geoed else None,
-        wan_offset=wan_off if geo is not None else None,
-    )
+        # completion-weighted blend of the probe-calibrated per-class costs:
+        # the measured msgs/cmd surface (float64, so exact stations stay exact)
+        msgs = (done_w[:, 0, None] * cost_w + done_r[:, 0, None] * cost_r) \
+            / np.maximum(lane_n, 1)[:, None]
+
+        return BatchedExecutionResult(
+            configs=tuple(dict(configs[int(ci)]) for ci in lane_cfg),
+            workload=w,
+            n_commands=n_commands,
+            n_clients=n_clients,
+            seeds=seeds_arr,
+            station_msgs=msgs,
+            n_writes=done_w[:, 0].copy(),
+            cost_write=cost_w,
+            cost_read=cost_r,
+            throughput=lane_n[:, None] / np.maximum(t_last, 1e-30),
+            latency_mean=lat_sum / np.maximum(done, 1) + wan_off[:, None],
+            latency_p50=_quantile_from_hist(hist, edges, 0.50),
+            latency_p99=_quantile_from_hist(hist, edges, 0.99),
+            completed=done.astype(np.float64),
+            hist=hist,
+            bin_edges=edges,
+            dt=dt,
+            n_steps=n_steps,
+            alpha=a,
+            sharding=sharding if sharded else None,
+            lane_config=lane_cfg if (sharded or geoed) else None,
+            lane_shard=lane_shard if sharded else None,
+            lane_commands=lane_n if (sharded or geoed) else None,
+            geo=geo,
+            lane_region=lane_shard if geoed else None,
+            wan_offset=wan_off if geo is not None else None,
+        )
 
 
 def run_variant_batched(name: str,

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .analytical import DeploymentModel
 
 
@@ -43,10 +44,11 @@ def _mva_scan_impl(demands: jnp.ndarray, think: jnp.ndarray, n_max: int):
     """
 
     def step(q, n):
-        r_k = demands * (1.0 + q)          # residence time per station
-        r = jnp.sum(r_k)
-        x = n / (think + r)                # closed-loop throughput
-        q_new = x * r_k                    # Little's law per station
+        with jax.named_scope("mva.step"):
+            r_k = demands * (1.0 + q)          # residence time per station
+            r = jnp.sum(r_k)
+            x = n / (think + r)                # closed-loop throughput
+            q_new = x * r_k                    # Little's law per station
         return q_new, (x, r)
 
     q0 = jnp.zeros_like(demands)
@@ -78,6 +80,7 @@ def mva_curve(model: DeploymentModel, alpha: float, n_clients_max: int = 512,
     return clients, np.asarray(xs), np.asarray(rs)
 
 
+@tracing.span("repro.mva")
 def mva_curves_from_demands(demands: np.ndarray, n_clients_max: int = 512,
                             think: float = 0.0
                             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -85,9 +88,12 @@ def mva_curves_from_demands(demands: np.ndarray, n_clients_max: int = 512,
     command per station, i.e. already divided by alpha).  One jitted call
     regardless of M - this is the kernel the sweep engine drives with
     thousands of compiled configs at once.  Returns (clients, X[M, N], R[M, N])."""
-    xs, rs = _mva_scan_batch(jnp.asarray(demands), jnp.asarray(think),
-                             n_clients_max)
-    return np.arange(1, n_clients_max + 1), np.asarray(xs), np.asarray(rs)
+    with tracing.span("repro.mva.dispatch"):
+        out = _mva_scan_batch(jnp.asarray(demands), jnp.asarray(think),
+                              n_clients_max)
+    tracing.wait("repro.mva.wait", out[0])
+    xs, rs = tracing.pull("repro.mva.pull", *out)
+    return np.arange(1, n_clients_max + 1), xs, rs
 
 
 def _padded_demands(models: Sequence[DeploymentModel], alpha: float,

@@ -46,6 +46,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from . import tracing
 from .analytical import (
     STATION_ORDER,
     DeploymentModel,
@@ -319,6 +320,7 @@ class CompiledSweep:
         k = self.demand_write.shape[1]
         return [f"s{i // k}/{STATION_ORDER[i % k]}" for i in idx]
 
+    @tracing.span("repro.mva")
     def mva(self, alpha: float, n_clients_max: int = 512,
             workload: Optional[Union[Workload, float]] = None,
             f_write: Optional[float] = None,
@@ -330,10 +332,12 @@ class CompiledSweep:
         rows flatten the [M, S, K] tensor to [M, S*K] first: the same
         jitted MVA kernel then solves every shard's station loads jointly
         (each column's demand is already visit-ratio-scaled)."""
-        d = self.demands(workload, f_write, sharding)
-        if sharding is not None:
-            d = flatten_shards(d)
-        return mva_curves_from_demands(d / alpha, n_clients_max)
+        with tracing.span("repro.mva.lower"):
+            d = self.demands(workload, f_write, sharding)
+            if sharding is not None:
+                d = flatten_shards(d)
+            d = d / alpha
+        return mva_curves_from_demands(d, n_clients_max)
 
     def geo_latency(self, alpha: float, geo: Any,
                     workload: Optional[Union[Workload, float]] = None,
@@ -382,6 +386,7 @@ class CompiledSweep:
         return fluid_throughput_from_demands(
             d / alpha, n_clients, sim_time, n_steps)
 
+    @tracing.span("repro.transient")
     def transient(self, alpha: float, n_clients: int = 64,
                   workload: Optional[Union[Workload, float]] = None,
                   f_write: Optional[float] = None,
@@ -397,26 +402,29 @@ class CompiledSweep:
         burst is one schedule).  Returns per-window throughput traces and
         latency p50/p99 - the figure-of-merit surface the autotuner ranks
         by under faults."""
-        w = resolve_workload(workload, f_write,
-                             where="CompiledSweep.transient")
-        evs = list(events) if events else []
-        if sharding is None:
-            base = self.demands(w) / alpha
-        else:
-            base = flatten_shards(self.demands(w, sharding=sharding)) / alpha
-            evs = _sharded_events(evs, self.demand_write.shape[1],
-                                  sharding.n_shards)
-        if w.arrival == "bursty":
-            evs.extend(burst_events(base.shape[1], factor=w.burst_factor,
-                                    fraction=w.burst_fraction,
-                                    n_bursts=w.n_bursts))
-        if evs:
-            sched, bounds = build_schedule(base, evs, n_steps)
-        else:
-            sched, bounds = base[None, :, :], None
+        with tracing.span("repro.transient.lower"):
+            w = resolve_workload(workload, f_write,
+                                 where="CompiledSweep.transient")
+            evs = list(events) if events else []
+            if sharding is None:
+                base = self.demands(w) / alpha
+            else:
+                base = (flatten_shards(self.demands(w, sharding=sharding))
+                        / alpha)
+                evs = _sharded_events(evs, self.demand_write.shape[1],
+                                      sharding.n_shards)
+            if w.arrival == "bursty":
+                evs.extend(burst_events(base.shape[1], factor=w.burst_factor,
+                                        fraction=w.burst_fraction,
+                                        n_bursts=w.n_bursts))
+            if evs:
+                sched, bounds = build_schedule(base, evs, n_steps)
+            else:
+                sched, bounds = base[None, :, :], None
         return simulate_transient(sched, bounds, n_clients=n_clients,
                                   n_steps=n_steps, **kwargs)
 
+    @tracing.span("repro.execute")
     def execute(self, workload: Optional[Union[Workload, float]] = None,
                 n_commands: int = 48, seeds: Union[int, Sequence[int]] = 4,
                 sharding: Optional[ShardingSpec] = None,

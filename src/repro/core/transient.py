@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .analytical import (
     STATION_INDEX,
     STATION_ORDER,
@@ -504,47 +505,54 @@ def _one_lane(demands_w, step_bounds, dt, entry, nxt, bin_edges, key,
         i, draw_i = xs
         t_end = (i + 1).astype(work.dtype) * dt
 
-        w = jnp.searchsorted(step_bounds, i, side="right") - 1
-        d_now = demands_w[w]                                   # [K]
-        # a window may zero an active station's demand ("free" service):
-        # drain instantly rather than stall (still capped at one
-        # completion per step, i.e. 1/dt per station)
-        rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
+        with jax.named_scope("transient.window"):
+            w = jnp.searchsorted(step_bounds, i, side="right") - 1
+            d_now = demands_w[w]                               # [K]
+            # a window may zero an active station's demand ("free"
+            # service): drain instantly rather than stall (still capped
+            # at one completion per step, i.e. 1/dt per station)
+            rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
 
-        busy = q > 0
-        work = jnp.where(busy, work - rate, work)
-        complete = busy & (work <= 0.0)                        # [K]
+        with jax.named_scope("transient.drain"):
+            busy = q > 0
+            work = jnp.where(busy, work - rate, work)
+            complete = busy & (work <= 0.0)                    # [K]
 
-        dep_here = complete[stage]                             # [N]
-        moving = dep_here & (rank == 0)
-        fin = moving & finishes_at[stage]                      # command done
-        lat = t_end - enter_t
-        rec = fin & (i >= warmup_steps)
-        done = done + jnp.sum(rec)
-        lat_sum = lat_sum + jnp.sum(jnp.where(rec, lat, 0.0))
-        bins = jnp.clip(jnp.searchsorted(bin_edges, lat) - 1, 0, n_bins - 1)
-        hist = hist.at[bins].add(rec.astype(jnp.int32))
+            dep_here = complete[stage]                         # [N]
+            moving = dep_here & (rank == 0)
+            fin = moving & finishes_at[stage]                  # command done
+            lat = t_end - enter_t
+            rec = fin & (i >= warmup_steps)
+            done = done + jnp.sum(rec)
+            lat_sum = lat_sum + jnp.sum(jnp.where(rec, lat, 0.0))
+        with jax.named_scope("transient.bin"):
+            bins = jnp.clip(jnp.searchsorted(bin_edges, lat) - 1, 0,
+                            n_bins - 1)
+            hist = hist.at[bins].add(rec.astype(jnp.int32))
 
-        dest = arrive_at[stage]                                # [N]
-        q_dep = q - complete.astype(q.dtype)
-        stage_new = jnp.where(moving, dest, stage)
-        enter_new = jnp.where(fin, t_end, enter_t)
-        rank_new = jnp.where(
-            moving, q_dep[dest],
-            rank - (dep_here & (rank > 0)).astype(rank.dtype))
-        arrivals = (jnp.zeros_like(q)
-                    .at[arrive_at].add(complete.astype(q.dtype)))
-        q_new = q_dep + arrivals
-        # per-window queue-depth integral: the autoscale controller's
-        # second signal (utilization says "how busy", queue depth says
-        # "how far behind") - a [W, K] running sum is ~n_steps/W cheaper
-        # to carry out of the scan than per-step queue traces
-        qsum = qsum.at[w].add(q_new.astype(qsum.dtype))
-        # new head enters service: carry the completion residual on a busy
-        # server (unbiased long-run rate), fresh draw on an idle one
-        fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
-        work_new = jnp.where(
-            fresh, draw_i + jnp.where(complete, work, 0.0), work)
+        with jax.named_scope("transient.route"):
+            dest = arrive_at[stage]                            # [N]
+            q_dep = q - complete.astype(q.dtype)
+            stage_new = jnp.where(moving, dest, stage)
+            enter_new = jnp.where(fin, t_end, enter_t)
+            rank_new = jnp.where(
+                moving, q_dep[dest],
+                rank - (dep_here & (rank > 0)).astype(rank.dtype))
+            arrivals = (jnp.zeros_like(q)
+                        .at[arrive_at].add(complete.astype(q.dtype)))
+            q_new = q_dep + arrivals
+            # per-window queue-depth integral: the autoscale controller's
+            # second signal (utilization says "how busy", queue depth
+            # says "how far behind") - a [W, K] running sum is
+            # ~n_steps/W cheaper to carry out of the scan than per-step
+            # queue traces
+            qsum = qsum.at[w].add(q_new.astype(qsum.dtype))
+            # new head enters service: carry the completion residual on a
+            # busy server (unbiased long-run rate), fresh draw on an idle
+            # one
+            fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
+            work_new = jnp.where(
+                fresh, draw_i + jnp.where(complete, work, 0.0), work)
 
         out_flow = jnp.sum(fin).astype(jnp.int32)
         return ((stage_new, rank_new, enter_new, q_new, work_new,
@@ -686,29 +694,11 @@ def _quantile_from_hist(hist: np.ndarray, edges: np.ndarray, q: float
     return lo * (hi / lo) ** frac
 
 
-def simulate_transient(
-    demands: np.ndarray,
-    step_bounds: Optional[np.ndarray] = None,
-    *,
-    n_clients: int = 64,
-    seeds: Union[int, Sequence[int]] = 8,
-    n_steps: int = 4000,
-    dt: Optional[Union[float, np.ndarray]] = None,
-    oversample: float = 4.0,
-    exponential_service: bool = True,
-    warmup_frac: float = 0.25,
-    n_bins: int = 96,
-) -> TransientResult:
-    """Run the batched engine over a (possibly scheduled) demand tensor.
-
-    demands: [W, M, K] piecewise windows (or [M, K] / [K] for a single
-    steady window), in seconds per command per station - i.e. already
-    divided by alpha, like :func:`simulator.mva_curves_from_demands`.
-    ``step_bounds[w]`` is the first step of window w (from
-    :func:`build_schedule` et al.); omitted = one window from step 0.
-    ``seeds`` is a count or explicit list; every (deployment, seed) lane
-    runs in ONE jitted call.  ``dt`` defaults per deployment to the
-    window-0 bottleneck demand / ``oversample``."""
+def _lower_transient(demands, step_bounds, n_steps, dt, oversample,
+                     n_bins, seeds, warmup_frac):
+    """:func:`simulate_transient`'s host lowering: the [W, M, K] demand
+    tensor, step bounds, routing, per-deployment dt, bin edges, seeds and
+    warm-up steps, validated."""
     d = np.asarray(demands, dtype=np.float64)
     if d.ndim == 1:
         d = d[None, :]
@@ -753,34 +743,66 @@ def simulate_transient(
     else:
         seeds_arr = np.asarray(list(seeds), dtype=np.int32)
     warmup_steps = int(n_steps * warmup_frac)
+    return (d, step_bounds, dt_arr, entry, nxt, bin_edges, seeds_arr,
+            warmup_steps)
 
-    flows, done, lat_sum, hist, qsum = _transient_batch(
-        jnp.asarray(d), jnp.asarray(step_bounds), jnp.asarray(dt_arr),
-        jnp.asarray(entry), jnp.asarray(nxt), jnp.asarray(bin_edges),
-        jnp.asarray(seeds_arr), n_clients=n_clients, n_steps=n_steps,
-        warmup_steps=warmup_steps, n_bins=n_bins,
-        exponential=bool(exponential_service))
-    flows = np.asarray(flows)
-    done = np.asarray(done)
-    lat_sum = np.asarray(lat_sum)
-    hist = np.asarray(hist)
-    qsum = np.asarray(qsum)
 
-    measured = dt_arr[:, None] * (n_steps - warmup_steps)
-    return TransientResult(
-        dt=dt_arr,
-        flows=flows,
-        throughput=done / measured,
-        latency_mean=lat_sum / np.maximum(done, 1),
-        latency_p50=_quantile_from_hist(hist, bin_edges, 0.50),
-        latency_p99=_quantile_from_hist(hist, bin_edges, 0.99),
-        completed=done,
-        hist=hist,
-        bin_edges=bin_edges,
-        n_steps=n_steps,
-        warmup_steps=warmup_steps,
-        queue_sums=qsum,
-    )
+@tracing.span("repro.transient")
+def simulate_transient(
+    demands: np.ndarray,
+    step_bounds: Optional[np.ndarray] = None,
+    *,
+    n_clients: int = 64,
+    seeds: Union[int, Sequence[int]] = 8,
+    n_steps: int = 4000,
+    dt: Optional[Union[float, np.ndarray]] = None,
+    oversample: float = 4.0,
+    exponential_service: bool = True,
+    warmup_frac: float = 0.25,
+    n_bins: int = 96,
+) -> TransientResult:
+    """Run the batched engine over a (possibly scheduled) demand tensor.
+
+    demands: [W, M, K] piecewise windows (or [M, K] / [K] for a single
+    steady window), in seconds per command per station - i.e. already
+    divided by alpha, like :func:`simulator.mva_curves_from_demands`.
+    ``step_bounds[w]`` is the first step of window w (from
+    :func:`build_schedule` et al.); omitted = one window from step 0.
+    ``seeds`` is a count or explicit list; every (deployment, seed) lane
+    runs in ONE jitted call.  ``dt`` defaults per deployment to the
+    window-0 bottleneck demand / ``oversample``."""
+    with tracing.span("repro.transient.lower"):
+        (d, step_bounds, dt_arr, entry, nxt, bin_edges, seeds_arr,
+         warmup_steps) = _lower_transient(
+            demands, step_bounds, n_steps, dt, oversample, n_bins, seeds,
+            warmup_frac)
+    with tracing.span("repro.transient.dispatch"):
+        out = _transient_batch(
+            jnp.asarray(d), jnp.asarray(step_bounds), jnp.asarray(dt_arr),
+            jnp.asarray(entry), jnp.asarray(nxt), jnp.asarray(bin_edges),
+            jnp.asarray(seeds_arr), n_clients=n_clients, n_steps=n_steps,
+            warmup_steps=warmup_steps, n_bins=n_bins,
+            exponential=bool(exponential_service))
+    tracing.wait("repro.transient.wait", out[0])
+    flows, done, lat_sum, hist, qsum = tracing.pull(
+        "repro.transient.pull", *out)
+
+    with tracing.span("repro.transient.reduce"):
+        measured = dt_arr[:, None] * (n_steps - warmup_steps)
+        return TransientResult(
+            dt=dt_arr,
+            flows=flows,
+            throughput=done / measured,
+            latency_mean=lat_sum / np.maximum(done, 1),
+            latency_p50=_quantile_from_hist(hist, bin_edges, 0.50),
+            latency_p99=_quantile_from_hist(hist, bin_edges, 0.99),
+            completed=done,
+            hist=hist,
+            bin_edges=bin_edges,
+            n_steps=n_steps,
+            warmup_steps=warmup_steps,
+            queue_sums=qsum,
+        )
 
 
 def transient_throughput(model: DeploymentModel, alpha: float,

@@ -10,13 +10,26 @@ with zero test edits, and can never break an unrelated hand-pinned list.
 """
 import pytest
 
-from repro.core import GeoSpec, executable_variants
+from repro.core import GeoSpec, api, executable_variants
 
 
 def pytest_generate_tests(metafunc):
     if "executable_variant" in metafunc.fixturenames:
         metafunc.parametrize("executable_variant",
                              list(executable_variants()))
+
+
+@pytest.fixture(autouse=True)
+def station_vocabulary():
+    """Give back the station vocabulary each test started with.  Slots a
+    test's runtime variants allocate are never reclaimed in a process
+    (compiled sweeps address columns by index), so without this every
+    later test in the same worker would see the extra columns."""
+    stations, slots = list(api._STATIONS), dict(api._STATION_SLOTS)
+    yield
+    api._STATIONS[:] = stations
+    api._STATION_SLOTS.clear()
+    api._STATION_SLOTS.update(slots)
 
 
 @pytest.fixture

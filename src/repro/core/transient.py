@@ -523,12 +523,22 @@ def _one_lane(demands_w, step_bounds, dt, entry, nxt, bin_edges, key,
             fin = moving & finishes_at[stage]                  # command done
             lat = t_end - enter_t
             rec = fin & (i >= warmup_steps)
-            done = done + jnp.sum(rec)
-            lat_sum = lat_sum + jnp.sum(jnp.where(rec, lat, 0.0))
+            n_rec = jnp.sum(rec)
+            lat_fin = jnp.sum(jnp.where(rec, lat, 0.0))
+            done = done + n_rec
+            lat_sum = lat_sum + lat_fin
         with jax.named_scope("transient.bin"):
-            bins = jnp.clip(jnp.searchsorted(bin_edges, lat) - 1, 0,
-                            n_bins - 1)
-            hist = hist.at[bins].add(rec.astype(jnp.int32))
+            # at most one completion per lane-step: the routing is a
+            # tandem, so only its last station completes commands, and a
+            # FIFO station departs only its rank-0 client.  So lat_fin is
+            # that one client's latency, and binning it alone gives the
+            # per-client histogram exactly.  One value against the edges
+            # is one fused compare ("compare_all"), not a loop of probes;
+            # the one-hot add is one fused op, not a scatter.
+            b = jnp.clip(jnp.searchsorted(bin_edges, lat_fin,
+                                          method="compare_all") - 1,
+                         0, n_bins - 1)
+            hist = hist + jnp.where(jnp.arange(n_bins) == b, n_rec, 0)
 
         with jax.named_scope("transient.route"):
             dest = arrive_at[stage]                            # [N]

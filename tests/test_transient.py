@@ -2,6 +2,10 @@
 DES engines, seeded determinism across vmapped lanes, scripted-event
 dynamics (failover dip + recovery, mid-run scale-up), and the batched
 (deployments x seeds)-in-one-jitted-call contract."""
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -20,10 +24,16 @@ from repro.core import (
     transient_throughput,
     unreplicated_model,
     SweepSpec,
+    Workload,
 )
 from repro.core.analytical import PAPER_MULTIPAXOS_UNBATCHED
 from repro.core.simulator import demand_vector
-from repro.core.transient import build_schedule, failover_schedule
+from repro.core.sweep import compile_models
+from repro.core.transient import (
+    _lower_transient,
+    build_schedule,
+    failover_schedule,
+)
 
 ALPHA = calibrate_alpha(PAPER_MULTIPAXOS_UNBATCHED)
 CMP = compartmentalized_model(f=1, n_proxy_leaders=10, grid_rows=2,
@@ -243,3 +253,159 @@ def test_schedule_builders():
                                             n_steps=100)
     assert list(bounds2) == [0, 50]
     np.testing.assert_allclose(sched2[1], 2 * base)
+
+
+# ---------------------------------------------------------------------------
+# One completion per lane-step: the scan bins one latency, not every client
+# ---------------------------------------------------------------------------
+
+
+def _per_client_lane(demands_w, step_bounds, dt, entry, nxt, bin_edges, key,
+                     n_clients, n_steps, warmup_steps, n_bins, exponential):
+    """The scan step with per-client binning: it bins every client's
+    latency and adds ``rec`` per client.  It also
+    carries the most commands any step completed, which the scan's
+    one-latency binning relies on being at most 1."""
+    n_windows, k = demands_w.shape
+    if exponential:
+        draws = jax.random.exponential(key, (n_steps + 1, k))
+    else:
+        draws = jnp.ones((n_steps + 1, k))
+    finishes_at = nxt == k
+    arrive_at = jnp.where(finishes_at, entry, nxt)
+
+    def step(state, xs):
+        (stage, rank, enter_t, q, work, done, lat_sum, hist, qsum,
+         most) = state
+        i, draw_i = xs
+        t_end = (i + 1).astype(work.dtype) * dt
+        w = jnp.searchsorted(step_bounds, i, side="right") - 1
+        d_now = demands_w[w]
+        rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
+        busy = q > 0
+        work = jnp.where(busy, work - rate, work)
+        complete = busy & (work <= 0.0)
+        dep_here = complete[stage]
+        moving = dep_here & (rank == 0)
+        fin = moving & finishes_at[stage]
+        lat = t_end - enter_t
+        rec = fin & (i >= warmup_steps)
+        done = done + jnp.sum(rec)
+        lat_sum = lat_sum + jnp.sum(jnp.where(rec, lat, 0.0))
+        bins = jnp.clip(jnp.searchsorted(bin_edges, lat) - 1, 0, n_bins - 1)
+        hist = hist.at[bins].add(rec.astype(jnp.int32))
+        most = jnp.maximum(most, jnp.sum(fin))
+        dest = arrive_at[stage]
+        q_dep = q - complete.astype(q.dtype)
+        stage_new = jnp.where(moving, dest, stage)
+        enter_new = jnp.where(fin, t_end, enter_t)
+        rank_new = jnp.where(
+            moving, q_dep[dest],
+            rank - (dep_here & (rank > 0)).astype(rank.dtype))
+        arrivals = jnp.zeros_like(q).at[arrive_at].add(
+            complete.astype(q.dtype))
+        q_new = q_dep + arrivals
+        qsum = qsum.at[w].add(q_new.astype(qsum.dtype))
+        fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
+        work_new = jnp.where(
+            fresh, draw_i + jnp.where(complete, work, 0.0), work)
+        return ((stage_new, rank_new, enter_new, q_new, work_new, done,
+                 lat_sum, hist, qsum, most), jnp.sum(fin).astype(jnp.int32))
+
+    state0 = (jnp.full((n_clients,), entry, dtype=jnp.int32),
+              jnp.arange(n_clients, dtype=jnp.int32),
+              jnp.zeros((n_clients,)),
+              jnp.zeros((k,), jnp.int32).at[entry].add(n_clients),
+              jnp.zeros((k,)).at[entry].set(draws[0, entry]),
+              jnp.asarray(0, jnp.int32), jnp.asarray(0.0),
+              jnp.zeros((n_bins,), jnp.int32), jnp.zeros((n_windows, k)),
+              jnp.asarray(0, jnp.int32))
+    xs = (jnp.arange(n_steps, dtype=jnp.int32), draws[1:])
+    (*_, done, lat_sum, hist, qsum, most), flows = jax.lax.scan(
+        step, state0, xs)
+    return flows, done, lat_sum, hist, qsum, most
+
+
+N_LANE_STEPS = 1200
+LANE_RUN = dict(n_clients=32, seeds=(3, 11), n_steps=N_LANE_STEPS)
+
+
+def _lane_schedules():
+    """Four deployments in canonical slots (tandems of four, two and one
+    active stations; an inactive leader in the unreplicated row) under
+    the three kinds of schedule the scan's callers build."""
+    base = compile_models([
+        CMP, multipaxos_model(f=1), unreplicated_model(),
+        compartmentalized_model(f=1, n_proxy_leaders=2, grid_rows=3,
+                                grid_cols=1, n_replicas=2),
+    ]).demands(Workload(f_write=1.0)) / ALPHA
+    n = N_LANE_STEPS
+    return {
+        "failover": failover_schedule(base, station="leader", start=0.4,
+                                      stop=0.6, n_steps=n),
+        "scale": scale_schedule(base, station="proxy", at=0.5, factor=0.5,
+                                n_steps=n),
+        "zero_demand": build_schedule(
+            base, [Event("proxy", 0.3, 0.7, 0.0),
+                   Event("replica", 0.3, 0.7, 0.0)], n_steps=n),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _per_client_run(schedule, exponential):
+    """Every (deployment, seed) lane of ``_per_client_lane`` on the inputs
+    ``simulate_transient`` lowers for ``LANE_RUN``; numpy arrays
+    [M, S, ...]."""
+    sched, bounds = _lane_schedules()[schedule]
+    d, step_bounds, dt, entry, nxt, edges, seeds_arr, warmup = (
+        _lower_transient(sched, bounds, N_LANE_STEPS, None, 4.0, 96,
+                         LANE_RUN["seeds"], 0.25))
+    keys = jax.vmap(lambda s: jax.random.fold_in(jax.random.key(0), s))(
+        seeds_arr)
+
+    @jax.jit
+    def run(d_w, dt_m, entry_m, nxt_m, edges_m):
+        def lanes(d_m, dt_1, entry_1, nxt_1, edges_1):
+            return jax.vmap(lambda key: _per_client_lane(
+                d_m, step_bounds, dt_1, entry_1, nxt_1, edges_1, key,
+                LANE_RUN["n_clients"], N_LANE_STEPS, warmup, 96,
+                exponential))(keys)
+        return jax.vmap(lanes, in_axes=(1, 0, 0, 0, 0))(
+            d_w, dt_m, entry_m, nxt_m, edges_m)
+
+    return [np.asarray(x) for x in run(d, dt, entry, nxt, edges)]
+
+
+LANE_CASES = pytest.mark.parametrize(
+    "schedule,exponential",
+    [(s, e) for s in ("failover", "scale", "zero_demand")
+     for e in (True, False)])
+
+
+@LANE_CASES
+def test_one_latency_binning_matches_per_client_binning(schedule,
+                                                        exponential):
+    """The scan bins the one latency a lane completes per step; on every
+    schedule kind its histogram, completions, mean latency and flows are
+    bit for bit those of binning every client."""
+    sched, bounds = _lane_schedules()[schedule]
+    res = simulate_transient(sched, bounds, exponential_service=exponential,
+                             **LANE_RUN)
+    flows, done, lat_sum, hist, _, _ = _per_client_run(schedule, exponential)
+    np.testing.assert_array_equal(res.hist, hist)
+    np.testing.assert_array_equal(res.completed, done)
+    np.testing.assert_array_equal(res.latency_mean,
+                                  lat_sum / np.maximum(done, 1))
+    np.testing.assert_array_equal(res.flows, flows)
+    np.testing.assert_array_equal(res.hist.sum(axis=2), res.completed)
+    assert res.completed.min() > 0
+
+
+@LANE_CASES
+def test_a_lane_completes_at_most_one_command_per_step(schedule,
+                                                       exponential):
+    """The invariant the scan's binning rests on: a tandem's last station
+    is FIFO, so no step completes two commands in one lane.  A routing
+    that broke it would make the scan undercount ``hist``."""
+    *_, most = _per_client_run(schedule, exponential)
+    assert most.max() == 1

@@ -90,6 +90,7 @@ from .analytical import (
     craq_station_demands,
     effective_batch_size,
     grids_under,
+    majority_grid,
     mencius_model,
     mixed_workload_speedup,
     multipaxos_model,
@@ -271,7 +272,7 @@ __all__ = [
     "failover_schedule", "flash_crowd_load", "flatten_shards",
     "fluid_throughput", "fluid_throughput_batch",
     "full_compartmentalized", "geo_station_kinds", "geo_variants",
-    "grids_under", "iss_model", "knob",
+    "grids_under", "iss_model", "knob", "majority_grid",
     "make_state_machine", "measured_capacity",
     "mencius_model", "mencius_skip_storm_schedule", "mixed_workload_speedup",
     "model_for", "multipaxos_model", "mva_curve", "mva_curves_batch",

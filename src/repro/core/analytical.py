@@ -198,17 +198,32 @@ def compartmentalized_model(
     batch_size: int = 1,
     n_batchers: int = 0,
     n_unbatchers: int = 0,
+    quorums: str = "grid",
 ) -> DeploymentModel:
     """Compartmentalized MultiPaxos (paper sections 3-4).
 
-    grid: write quorum = column (``grid_rows`` members), read quorum = row
-    (``grid_cols`` members).  ``batch_size=1`` means unbatched.
+    ``quorums="grid"``: a ``grid_rows x grid_cols`` grid, write quorum =
+    column (``grid_rows`` members), read quorum = row (``grid_cols``
+    members).  ``quorums="majority"``: the ``(2f+1, 1)`` column of 2f+1
+    acceptors under majority quorums, a thrifty f+1 of them contacted per
+    write and per read (the paper's Fig. 29a acceptors before the grid).
+    ``batch_size=1`` means unbatched.
     """
     r, w = grid_rows, grid_cols
-    n_acc = r * w
     B = float(batch_size)
-    col = r  # write-quorum size
-    row = w  # read-quorum size
+    if quorums == "grid":
+        n_acc = r * w
+        col = r  # write-quorum size
+        row = w  # read-quorum size
+    elif quorums == "majority":
+        if (r, w) != (2 * f + 1, 1):
+            raise ValueError(
+                f"majority quorums span the (2f+1, 1) = ({2 * f + 1}, 1) "
+                f"acceptor column, not a {r}x{w} grid")
+        n_acc = 2 * f + 1
+        col = row = f + 1  # a thrifty majority, for writes and reads
+    else:
+        raise ValueError(f"quorums must be 'grid' or 'majority': {quorums!r}")
 
     stations: List[Station] = []
     if n_batchers > 0:
@@ -230,8 +245,13 @@ def compartmentalized_model(
                 proxy_per_batch / B / max(n_proxy_leaders, 1), 0.0))
 
     # acceptor: writes hit one column (2 msgs each member) -> 2/w per write;
-    # reads hit one row (2 msgs each member) -> 2/r per read
-    stations.append(Station("acceptor", n_acc, 2.0 / w / B, 2.0 / r / B))
+    # reads hit one row (2 msgs each member) -> 2/r per read.  A majority
+    # spreads its f+1 members over the 2f+1 acceptors evenly.
+    if quorums == "grid":
+        acc_w, acc_r = 2.0 / w / B, 2.0 / r / B
+    else:
+        acc_w = acc_r = 2.0 * col / n_acc / B
+    stations.append(Station("acceptor", n_acc, acc_w, acc_r))
 
     # replica: every replica receives+executes every write; one replica
     # executes each read; replies owned round-robin (writes) / direct (reads)
@@ -245,7 +265,8 @@ def compartmentalized_model(
         stations.append(Station("unbatcher", n_unbatchers, d_ub, d_ub))
 
     return DeploymentModel(
-        name=(f"compartmentalized(f={f},p={n_proxy_leaders},grid={r}x{w},"
+        name=(f"compartmentalized(f={f},p={n_proxy_leaders},"
+              f"{'grid' if quorums == 'grid' else 'majority'}={r}x{w},"
               f"n={n_replicas},B={batch_size})"),
         stations=tuple(stations),
     )
@@ -625,27 +646,23 @@ def read_scalability_law(n_replicas: float, f_write: Union[float, Workload],
 
 
 def ablation_steps(f: int = 1) -> List[Tuple[str, DeploymentModel]]:
-    """The paper's Fig. 29a sequence: decouple, then scale each bottleneck."""
+    """The paper's Fig. 29a sequence: decouple, then scale each bottleneck.
+
+    Until the last step the 2f+1 acceptors use majority quorums, as the
+    paper's decoupled deployment does; the last step is the 2x2 grid."""
+    def majority(p: int, n: int) -> DeploymentModel:
+        return compartmentalized_model(f=f, n_proxy_leaders=p,
+                                       grid_rows=2 * f + 1, grid_cols=1,
+                                       n_replicas=n, quorums="majority")
+
     return [
         ("multipaxos", multipaxos_model(f=f)),
-        ("decoupled (2 proxies, 3 acc, 2 repl)",
-         compartmentalized_model(f=f, n_proxy_leaders=2, grid_rows=3, grid_cols=1,
-                                 n_replicas=2)),
-        ("3 proxy leaders",
-         compartmentalized_model(f=f, n_proxy_leaders=3, grid_rows=3, grid_cols=1,
-                                 n_replicas=2)),
-        ("5 proxy leaders",
-         compartmentalized_model(f=f, n_proxy_leaders=5, grid_rows=3, grid_cols=1,
-                                 n_replicas=2)),
-        ("7 proxy leaders",
-         compartmentalized_model(f=f, n_proxy_leaders=7, grid_rows=3, grid_cols=1,
-                                 n_replicas=2)),
-        ("3 replicas",
-         compartmentalized_model(f=f, n_proxy_leaders=7, grid_rows=3, grid_cols=1,
-                                 n_replicas=3)),
-        ("10 proxy leaders",
-         compartmentalized_model(f=f, n_proxy_leaders=10, grid_rows=3, grid_cols=1,
-                                 n_replicas=3)),
+        ("decoupled (2 proxies, 3 acc, 2 repl)", majority(2, 2)),
+        ("3 proxy leaders", majority(3, 2)),
+        ("5 proxy leaders", majority(5, 2)),
+        ("7 proxy leaders", majority(7, 2)),
+        ("3 replicas", majority(7, 3)),
+        ("10 proxy leaders", majority(10, 3)),
         ("paper deployment (10 proxies, 2x2 grid, 4 replicas)",
          compartmentalized_model(f=f, n_proxy_leaders=10, grid_rows=2, grid_cols=2,
                                  n_replicas=4)),
@@ -673,13 +690,20 @@ def mixed_workload_speedup(f_write: float, alpha: float,
 # ---------------------------------------------------------------------------
 
 
-def grids_under(max_cells: int, f: int) -> List[Tuple[int, int]]:
+def majority_grid(f: int) -> Tuple[int, int, str]:
+    """The ``grids`` knob value of the 2f+1 acceptors under majority
+    quorums: the ``(2f+1, 1)`` column with ``quorums="majority"``."""
+    return (2 * f + 1, 1, "majority")
+
+
+def grids_under(max_cells: int, f: int) -> List[Tuple[Any, ...]]:
     """Acceptor grids with write quorums (columns) of >= f + 1 members and
-    at most ``max_cells`` acceptors, plus the (2f+1, 1) majority column."""
-    grids: List[Tuple[int, int]] = [(2 * f + 1, 1)]
+    at most ``max_cells`` acceptors; the ``(2f+1, 1)`` shape is taken as
+    the 2f+1 majority column (:func:`majority_grid`)."""
+    grids: List[Tuple[Any, ...]] = [majority_grid(f)]
     for rows in range(f + 1, max(max_cells, f + 1) + 1):
         for cols in range(1, max(max_cells // rows, 1) + 1):
-            if rows * cols <= max_cells and (rows, cols) not in grids:
+            if rows * cols <= max_cells and (rows, cols) != (2 * f + 1, 1):
                 grids.append((rows, cols))
     return grids
 
@@ -768,7 +792,8 @@ register_variant(
               "unbatcher"),
     knobs=(
         knob("n_proxy_leaders", (10,)),
-        knob("grids", ((2, 2),), keys=("grid_rows", "grid_cols")),
+        knob("grids", ((2, 2),), keys=("grid_rows", "grid_cols", "quorums"),
+             optional=1),
         knob("n_replicas", (4,)),
         knob("batch_sizes", (1,), keys=("batch_size",)),
         knob("n_batchers", (0,)),

@@ -675,8 +675,10 @@ class ExecutableSpec:
       knobs such as ``payload_factor`` are accepted and ignored);
     * ``station_of(addr, deployment) -> station | None`` buckets a node
       address into the canonical station vocabulary (``None`` = not a
-      station, e.g. clients).  Default: the ``role/<i>`` address prefix
-      when it names a declared station;
+      station, e.g. clients; ``(station, "sent")`` counts only what the
+      node sends, for a fused machine's role whose receipts are local).
+      Default: the ``role/<i>`` address prefix when it names a declared
+      station;
     * ``model_feedback(model_config, trace) -> model_config`` optionally
       feeds *measured* run statistics back into the demand table before
       the parity comparison (Mencius: the observed noop-skip rate and the
@@ -698,7 +700,8 @@ class ExecutableSpec:
     """
 
     deployment: Callable[..., Any]
-    station_of: Optional[Callable[[str, Any], Optional[str]]] = None
+    station_of: Optional[
+        Callable[[str, Any], Union[None, str, Tuple[str, str]]]] = None
     model_feedback: Optional[Callable[[Config, Any], Config]] = None
     rel_tolerance: float = 0.15
     station_tolerances: Tuple[Tuple[str, float], ...] = ()
@@ -723,11 +726,15 @@ class Knob:
     built-ins, a ``knob_values`` key for runtime variants); ``keys`` are
     the config-dict entries one value sets.  A coupled knob has several
     keys and tuple values - e.g. the acceptor grid: ``name="grids"``,
-    ``keys=("grid_rows", "grid_cols")``, values like ``(2, 2)``."""
+    ``keys=("grid_rows", "grid_cols")``, values like ``(2, 2)``.  The last
+    ``optional`` keys may be left off a value and then keep the factory's
+    default (compartmentalized grids: ``(2, 2)``, or ``(3, 1,
+    "majority")``, which sets ``quorums`` too)."""
 
     name: str
     keys: Tuple[str, ...]
     values: Tuple[Any, ...]
+    optional: int = 0
 
     def __post_init__(self) -> None:
         if not self.keys:
@@ -741,7 +748,7 @@ class Knob:
             yield self.keys[0], value
             return
         vt = tuple(value)
-        if len(vt) != len(self.keys):
+        if not len(self.keys) - self.optional <= len(vt) <= len(self.keys):
             raise ValueError(
                 f"knob {self.name!r} couples {len(self.keys)} keys "
                 f"{self.keys} but got value {value!r}")
@@ -749,10 +756,10 @@ class Knob:
 
 
 def knob(name: str, values: Sequence[Any],
-         keys: Optional[Sequence[str]] = None) -> Knob:
+         keys: Optional[Sequence[str]] = None, optional: int = 0) -> Knob:
     """Convenience :class:`Knob` builder (``keys`` defaults to ``name``)."""
     return Knob(name=name, keys=tuple(keys) if keys is not None else (name,),
-                values=tuple(values))
+                values=tuple(values), optional=optional)
 
 
 @dataclass(frozen=True)

@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
-from .analytical import DeploymentModel, multipaxos_model
+from .analytical import DeploymentModel, majority_grid, multipaxos_model
 from .api import (
     STATION_INDEX,
     AutoscalePolicy,
@@ -101,8 +101,9 @@ def candidate_spec(budget: int, f: int = 1, batching: bool = False,
     """The discrete config space under a machine budget.
 
     Grids keep write quorums (columns) of at least ``f + 1`` members so f
-    failures are survivable; the ``(2f+1, 1)`` column is the
-    majority-quorum degenerate case the ablation starts from.  Knob ranges
+    failures are survivable; the 2f+1 acceptors under majority quorums
+    (:func:`~repro.core.analytical.majority_grid`) are the case the
+    ablation starts from.  Knob ranges
     are clipped so the *smallest* other components still fit: anything
     larger can never be feasible and would only bloat the batch.  The
     unbatched clipping is the compartmentalized variant's registered
@@ -126,7 +127,7 @@ def candidate_spec(budget: int, f: int = 1, batching: bool = False,
     return SweepSpec(
         f=f,
         n_proxy_leaders=tuple(range(1, min(max_proxies, 4) + 1)),
-        grids=((2 * f + 1, 1), (f + 1, f + 1)),
+        grids=(majority_grid(f), (f + 1, f + 1)),
         n_replicas=tuple(range(f + 1, min(max_replicas, f + 3) + 1)),
         batch_sizes=batch_sizes,
         n_batchers=tuple(range(1, min(max_bu, 12) + 1)),
@@ -149,14 +150,16 @@ def _eval(config: Config, alpha: float, workload: Workload
 # knob-turn candidates per bottleneck station: (label, config transform)
 def _moves(config: Config, batching: bool) -> Dict[str, List[Tuple[str, Config]]]:
     r, w = config["grid_rows"], config["grid_cols"]
+    # growing the acceptors turns the majority column into a grid
+    grid = {k: v for k, v in config.items() if k != "quorums"}
     moves: Dict[str, List[Tuple[str, Config]]] = {
         "proxy": [("+1 proxy leader",
                    {**config, "n_proxy_leaders": config["n_proxy_leaders"] + 1})],
         "replica": [("+1 replica",
                      {**config, "n_replicas": config["n_replicas"] + 1})],
         "acceptor": [
-            ("+1 grid column (write sharding)", {**config, "grid_cols": w + 1}),
-            ("+1 grid row (read sharding)", {**config, "grid_rows": r + 1}),
+            ("+1 grid column (write sharding)", {**grid, "grid_cols": w + 1}),
+            ("+1 grid row (read sharding)", {**grid, "grid_rows": r + 1}),
         ],
         "batcher": [], "unbatcher": [], "leader": [],
     }
@@ -198,8 +201,8 @@ def bottleneck_trace(budget: int, alpha: float,
     # paper Fig. 29a step 1: decouple into 2 proxies, 2f+1 acceptors, f+1
     # replicas (1 proxy would *lose* throughput vs the fused leader)
     config: Config = dict(f=f, n_proxy_leaders=2, grid_rows=2 * f + 1,
-                          grid_cols=1, n_replicas=f + 1, batch_size=1,
-                          n_batchers=0, n_unbatchers=0)
+                          grid_cols=1, quorums="majority", n_replicas=f + 1,
+                          batch_size=1, n_batchers=0, n_unbatchers=0)
     peak, bn, machines, total = _eval(config, alpha, w)
     if machines > budget:
         return trace

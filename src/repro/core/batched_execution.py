@@ -600,6 +600,12 @@ def execute_configs(
             # and quantiles both read as total (wire + queueing) latency
             edges = edges + wan_off[:, None]
 
+        # steps each lane ran to its last completion (t_last is the end of
+        # that step, (i + 1) * dt), against the steps the scan ran them
+        tracing.count("repro.execute.lane_steps",
+                      int(np.rint(t_last / dt[:, None]).sum()))
+        tracing.count("repro.execute.scan_lane_steps", m * s * n_steps)
+
         # float64 sums, one config row at a time to bound host memory
         lat_sum = np.stack([np.where(fin[i], lat[i].astype(np.float64), 0.0)
                             .sum(axis=(1, 2)) for i in range(m)])

@@ -41,6 +41,7 @@ import random
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Tuple, Union
 
+from .analytical import compartmentalized_model
 from .api import (
     Config,
     ExecutableSpec,
@@ -62,6 +63,7 @@ from .protocols import (
     DeploymentConfig,
     UnreplicatedStateMachine,
 )
+from .quorums import GridQuorums
 from .sharding import partition_history, partition_ops
 from .spaxos import SPaxosDeployment, VanillaSPaxosDeployment
 
@@ -275,6 +277,10 @@ def _station_msgs(spec: Any, exe: ExecutableSpec, dep: Any,
             role = addr.split("/", 1)[0]
             station = role if role in spec.stations else None
         if station is None:
+            continue
+        if isinstance(station, tuple):  # (station, "sent"): a fused role
+            station = station[0]
+            totals[station] = totals.get(station, 0) + node.msgs_sent
             continue
         totals[station] = totals.get(station, 0) + (node.msgs_sent
                                                     + node.msgs_received)
@@ -1128,14 +1134,25 @@ def _compartmentalized_deployment(f: int = 1, n_proxy_leaders: int = 10,
                                   grid_rows: int = 2, grid_cols: int = 2,
                                   n_replicas: int = 4, batch_size: int = 1,
                                   n_batchers: int = 0, n_unbatchers: int = 0,
+                                  quorums: str = "grid",
                                   n_clients: int = 3, seed: int = 0,
                                   state_machine: str = "kv",
                                   latency_fn: Optional[Any] = None,
                                   ) -> CompartmentalizedMultiPaxos:
-    # the (2f+1, 1) "grid" is the majority-quorum column: lower it to the
-    # majority quorum system the deployment uses for that shape
-    grid = None if (grid_rows, grid_cols) == (2 * f + 1, 1) else (grid_rows,
-                                                                  grid_cols)
+    # the quorum system the table prices (whose checks of the knobs this
+    # runs): majorities over the 2f+1 column, or the grid itself - one
+    # the cluster can run with f failures
+    compartmentalized_model(f=f, grid_rows=grid_rows, grid_cols=grid_cols,
+                            quorums=quorums)
+    if quorums == "majority":
+        grid = None
+    elif GridQuorums(rows=grid_rows, cols=grid_cols).tolerates(f):
+        grid = (grid_rows, grid_cols)
+    else:
+        raise ValueError(
+            f"a {grid_rows}x{grid_cols} acceptor grid does not tolerate f={f} "
+            f"(GridQuorums needs rows and cols >= f + 1); the 2f+1 majority "
+            f"column is quorums='majority'")
     cfg = DeploymentConfig(f=f, n_proxy_leaders=n_proxy_leaders, grid=grid,
                            n_replicas=n_replicas, n_batchers=n_batchers,
                            n_unbatchers=n_unbatchers, batch_size=batch_size,
@@ -1186,15 +1203,20 @@ def _multipaxos_deployment(f: int = 1, thrifty: bool = True,
     return CompartmentalizedMultiPaxos(cfg, n_clients=n_clients)
 
 
-def _multipaxos_station_of(addr: str, dep: Any) -> Optional[str]:
-    """Fused-server bucketing for the vanilla baseline: the model's
-    ``leader`` station is machine 0 (the leader role; its colocated
-    acceptor/replica role costs are the model's reply-share term) and
-    ``follower`` the other 2f machines (acceptor + replica roles).  The
-    standby leader objects are idle and unmapped."""
+def _multipaxos_station_of(addr: str, dep: Any
+                           ) -> Union[None, str, Tuple[str, str]]:
+    """Fused-server bucketing after ``multipaxos_model``'s accounting.
+    Machine 0 is the ``leader``: its leader role's messages plus the
+    replies its replica role sends (the table's reply share); the Phase 2
+    messages of its acceptor role and the chosen its replica role gets
+    from its own leader are local, and counted on neither side.  The other
+    2f machines are ``follower``s (acceptor + replica roles).  The standby
+    leader objects are idle and unmapped."""
     role, _, idx = addr.partition("/")
     if role == "leader":
         return "leader" if idx == "0" else None
+    if role == "replica" and idx == "0":
+        return ("leader", "sent")
     if role in ("acceptor", "replica"):
         return None if idx == "0" else "follower"
     return None
@@ -1363,11 +1385,13 @@ def _unreplicated_deployment(n_clients: int = 2, seed: int = 0,
 # * compartmentalized / spaxos: station totals per command are
 #   deterministic (random quorum/column picks move messages *within* a
 #   station, never across), so tolerances are tight and the headline
-#   leader counts (2 msgs/cmd; S-Paxos: 2 id-only msgs) are exact.
-# * multipaxos: the fused-machine model folds the leader machine's
-#   acceptor role and chosen-recv into its follower/reply terms slightly
-#   differently than the wire counts them - the leader row lands within
-#   ~5%, followers are exact in expectation.
+#   leader counts (2 msgs/cmd; S-Paxos: 2 id-only msgs) are exact.  Grids
+#   and the 2f+1 majority column (quorums="majority") are the systems the
+#   table prices; a grid that cannot tolerate f is refused.
+# * multipaxos: the fused machines are bucketed as multipaxos_model counts
+#   them and thrifty majorities rotate with the slot, so both rows are
+#   exact when the command count is a multiple of 2f+1 (the reply share
+#   of n = 2f+1 replicas rotates with the slot too).
 # * mencius: exact once the run's announce/skip parameters are fed back;
 #   the proxy row absorbs range-path edge messages.
 # * craq: message-exact chain accounting; under mixed workloads the

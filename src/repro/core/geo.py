@@ -185,12 +185,11 @@ def _reject_batching(cfg: Config, variant: str) -> None:
 def _acceptor_quorums(cfg: Config, f: int
                       ) -> Tuple[int, List[Tuple[int, ...]],
                                  List[Tuple[int, ...]]]:
-    """(n_acceptors, write quorums, read quorums) for a grid config;
-    the ``(2f+1, 1)`` grid lowers to majority quorums exactly like the
-    compartmentalized deployment does."""
+    """(n_acceptors, write quorums, read quorums) for a grid config, or
+    for the 2f+1 majority column of a ``quorums="majority"`` config."""
     rows = int(cfg.get("grid_rows", 2))
     cols = int(cfg.get("grid_cols", 2))
-    if (rows, cols) == (2 * f + 1, 1):
+    if cfg.get("quorums", "grid") == "majority":
         n = 2 * f + 1
         maj = _majority_quorums(n, f + 1)
         return n, maj, maj

@@ -87,6 +87,12 @@ class MajorityQuorums(QuorumSystem):
     def write_quorums(self) -> List[FrozenSet[int]]:
         return self._majorities()
 
+    def rotation(self, slot: int) -> FrozenSet[int]:
+        """The thrifty write quorum of ``slot``: the f+1 acceptors from
+        ``slot mod (2f+1)`` on, so that any 2f+1 consecutive slots contact
+        every acceptor exactly f+1 times."""
+        return frozenset((slot + i) % self.n for i in range(self.f + 1))
+
 
 @dataclass(frozen=True)
 class GridQuorums(QuorumSystem):

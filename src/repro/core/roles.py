@@ -45,10 +45,25 @@ from .messages import (
     is_noop,
     noop_command,
 )
-from .quorums import QuorumSystem, pick_read_quorum, pick_write_quorum
+from .quorums import (
+    MajorityQuorums,
+    QuorumSystem,
+    pick_read_quorum,
+    pick_write_quorum,
+)
 from .statemachine import StateMachine
 
 MAX_LEADERS = 64  # ballot = round * MAX_LEADERS + leader_index
+
+
+def _write_quorum(quorums: QuorumSystem, slot: int,
+                  rng: random.Random) -> FrozenSet[int]:
+    """The thrifty Phase 2 quorum of a slot: majorities rotate with the
+    slot (:meth:`MajorityQuorums.rotation`, an exact per-acceptor load over
+    any 2f+1 consecutive slots); other systems draw one."""
+    if isinstance(quorums, MajorityQuorums):
+        return quorums.rotation(slot)
+    return pick_write_quorum(quorums, rng.randrange(1 << 30))[1]
 
 
 # ---------------------------------------------------------------------------
@@ -184,7 +199,7 @@ class Leader(Node):
             self.send(proxy, msg)
 
     def _broadcast_phase2a(self, msg: Phase2a) -> None:
-        _, members = pick_write_quorum(self.quorums, self.rng.randrange(1 << 30))
+        members = _write_quorum(self.quorums, msg.slot, self.rng)
         self.pending2[msg.slot] = (msg.ballot, msg.value, set())
         for a in members:
             self.send(self.acceptors[a], msg)
@@ -290,7 +305,7 @@ class ProxyLeader(Node):
         if isinstance(msg, Phase2a):
             if msg.slot in self.done:
                 return
-            _, members = pick_write_quorum(self.quorums, self.rng.randrange(1 << 30))
+            members = _write_quorum(self.quorums, msg.slot, self.rng)
             self.pending[msg.slot] = (msg.ballot, msg.value, set())
             for a in members:
                 self.send(self.acceptors[a], msg)

@@ -196,13 +196,14 @@ def test_slotless_histories_never_get_a_vacuous_verdict():
 
 
 def test_calibrate_alpha_measured_matches_wire_counts():
-    """The executed vanilla leader handles exactly 3f+4+1 = 8 msgs/cmd
-    (client in, 2 p2a out, 2 p2b in, 3 chosen out at 2f+1 replicas), so
-    the measured anchor is 25k * 8."""
+    """The executed vanilla leader machine handles 3f+4+1 = 8 msgs/cmd
+    (client in, 2 p2a out, 2 p2b in, 3 chosen out at 2f+1 replicas) plus
+    its replica role's replies to 1/(2f+1) of the commands, as the table
+    counts it - so over a multiple of 2f+1 commands the measured anchor
+    is the table's, 25k * 8.333."""
     alpha = calibrate_alpha(measured=True, n_commands=30)
-    assert alpha == pytest.approx(25_000.0 * 8.0)
-    # the table-derived anchor folds the fused machine's reply share in
-    assert calibrate_alpha() > alpha
+    assert alpha == pytest.approx(25_000.0 * (8.0 + 1.0 / 3.0), rel=1e-12)
+    assert calibrate_alpha() == pytest.approx(alpha, rel=1e-12)
     with pytest.raises(TypeError, match="model=None"):
         calibrate_alpha(measured=True, model=object())
 

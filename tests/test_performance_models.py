@@ -38,11 +38,13 @@ def test_compartmentalized_write_bottleneck_is_leader():
 
 
 def test_decoupling_alone_shifts_bottleneck_to_proxies():
-    """Paper Fig. 29a: right after decoupling (2 proxies), proxies bottleneck."""
+    """Paper Fig. 29a: right after decoupling (2 proxies over the 2f+1
+    majority-quorum acceptors), proxies bottleneck."""
     m = compartmentalized_model(f=1, n_proxy_leaders=2, grid_rows=3,
-                                grid_cols=1, n_replicas=2)
-    name, _ = m.bottleneck()
+                                grid_cols=1, n_replicas=2, quorums="majority")
+    name, d = m.bottleneck()
     assert name == "proxy"
+    assert d == (1 + 2 + 2 + 2) / 2
 
 
 def test_write_only_speedup_matches_paper_band():

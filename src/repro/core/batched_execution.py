@@ -35,7 +35,8 @@ chosen per the *class of the command at the head* (writes traverse the
 write path's demands, reads the read path's), commands walking the active
 stations in canonical slot order.  Clients park once their op budget
 drains, so the run has a makespan - measured throughput is
-``n_commands / t_last`` - and every completion emits a latency sample;
+``n_commands / t_last`` - and the device loop stops once every lane has
+drained; every completion emits a latency sample;
 the samples are histogrammed post-scan by the Pallas
 :func:`repro.kernels.ops.latency_hist` kernel with the transient plane's
 binning, so p50/p99 read identically across planes.
@@ -138,10 +139,23 @@ def _class_streams(n_commands: int, f_write: float, n_clients: int,
 # ---------------------------------------------------------------------------
 
 
-def _one_exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
-                   n_steps: int, n_clients: int, exponential: bool):
-    """d_w/d_r: [K] per-class service seconds; nxt: [K] tandem routing;
-    cls_stream: [N, L] int32 op classes per client; budget: [N]."""
+# Steps per iteration of the execute scan's device loop.  The loop stops at
+# the first such boundary after every lane of the batch has drained its op
+# budget; the step bound is a multiple of it.
+SCAN_CHUNK = 256
+# the names of _execute_batch's two lane vmaps, over which a lane asks
+# whether any lane of the batch still has ops to complete
+LANE_AXES = ("config", "seed")
+
+
+def _exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
+               n_steps: int, n_clients: int, exponential: bool):
+    """One lane's initial state, step function and per-step service draws.
+
+    d_w/d_r: [K] per-class service seconds; nxt: [K] tandem routing;
+    cls_stream: [N, L] int32 op classes per client; budget: [N].
+    ``step(state, (i, draws[i])) -> (state, (fin[N], lat[N]))`` runs step
+    ``i``; ``state[6:9]`` are done_w, done_r and t_last."""
     k = d_w.shape[0]
     n_ops = cls_stream.shape[1]
     if exponential:
@@ -225,8 +239,53 @@ def _one_exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
               jnp.zeros((n_clients,), jnp.int32), q0, work0,
               jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
               jnp.asarray(0.0))
-    xs = (jnp.arange(n_steps, dtype=jnp.int32), draws[1:])
-    (state_f, (fin, lat)) = jax.lax.scan(step, state0, xs)
+    return state0, step, draws[1:]
+
+
+def _one_exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
+                   n_steps: int, n_clients: int, exponential: bool):
+    """Run one lane under ``_execute_batch``'s lane vmaps; returns
+    (fin[n_steps, N], lat[n_steps, N], done_w, done_r, t_last).
+
+    The steps run in chunks of ``SCAN_CHUNK`` inside a ``while_loop`` that
+    ends once no lane of the batch has ops left (or at ``n_steps``).  The
+    count of such lanes is a ``psum`` over ``LANE_AXES``, so the predicate
+    is one value for the whole batch: the vmapped loop stays a loop and
+    is not turned into a select over both branches.  Each step writes its
+    samples in place into the zeroed ``fin``/``lat`` buffers; ``fin``
+    stays False on the steps that never ran."""
+    if n_steps % SCAN_CHUNK:
+        raise ValueError(
+            f"n_steps={n_steps} is not a multiple of {SCAN_CHUNK}")
+    state0, step, draws = _exec_lane(d_w, d_r, entry, nxt, cls_stream,
+                                     budget, dt, key, n_steps, n_clients,
+                                     exponential)
+    total = jnp.sum(budget)
+
+    def write(carry, xs):
+        state, fin, lat = carry
+        state, (fin_i, lat_i) = step(state, xs)
+        fin = jax.lax.dynamic_update_index_in_dim(fin, fin_i, xs[0], 0)
+        lat = jax.lax.dynamic_update_index_in_dim(lat, lat_i, xs[0], 0)
+        return (state, fin, lat), None
+
+    def undrained(carry):
+        chunk, (state, _, _) = carry
+        left = (state[6] + state[7] < total).astype(jnp.int32)
+        return ((chunk < n_steps // SCAN_CHUNK)
+                & (jax.lax.psum(left, LANE_AXES) > 0))
+
+    def run_chunk(carry):
+        chunk, lane = carry
+        i0 = chunk * SCAN_CHUNK
+        xs = (i0 + jnp.arange(SCAN_CHUNK, dtype=jnp.int32),
+              jax.lax.dynamic_slice_in_dim(draws, i0, SCAN_CHUNK))
+        return chunk + 1, jax.lax.scan(write, lane, xs)[0]
+
+    lane0 = (state0, jnp.zeros((n_steps, n_clients), bool),
+             jnp.zeros((n_steps, n_clients)))
+    _, (state_f, fin, lat) = jax.lax.while_loop(
+        undrained, run_chunk, (jnp.asarray(0, jnp.int32), lane0))
     _, _, _, _, _, _, done_w, done_r, t_last = state_f
     return fin, lat, done_w, done_r, t_last
 
@@ -239,7 +298,9 @@ def _execute_batch(d_w, d_r, entry, nxt, cls, budget, dt, seeds,
     d_w/d_r: [M, K]; entry: [M]; nxt: [M, K]; cls: [M, S, N, L];
     budget: [M, N]; dt: [M]; seeds: [S].  Returns
     (fin[M, S, n_steps, N] bool, lat[M, S, n_steps, N], done_w[M, S],
-    done_r[M, S], t_last[M, S])."""
+    done_r[M, S], t_last[M, S]); the device stops at the first
+    ``SCAN_CHUNK`` boundary after every lane has drained its budget, and
+    ``fin`` is False on the steps after it."""
     m_ids = jnp.arange(d_w.shape[0], dtype=jnp.int32)
 
     def per_config(d_w_m, d_r_m, entry_m, nxt_m, cls_m, budget_m, dt_m, mi):
@@ -249,9 +310,10 @@ def _execute_batch(d_w, d_r, entry, nxt, cls, budget, dt, seeds,
             return _one_exec_lane(d_w_m, d_r_m, entry_m, nxt_m, cls_ms,
                                   budget_m, dt_m, key, n_steps, n_clients,
                                   exponential)
-        return jax.vmap(per_seed)(cls_m, seeds)
+        return jax.vmap(per_seed, axis_name=LANE_AXES[1])(cls_m, seeds)
 
-    return jax.vmap(per_config)(d_w, d_r, entry, nxt, cls, budget, dt, m_ids)
+    return jax.vmap(per_config, axis_name=LANE_AXES[0])(
+        d_w, d_r, entry, nxt, cls, budget, dt, m_ids)
 
 
 def _routing(active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -550,7 +612,8 @@ def execute_configs(
         steps = span / dt + (lane_n + n_clients) * active.sum(axis=1)
         margin = 4.0 if exponential_service else 1.3
         n_steps = int(math.ceil(margin * float(steps.max()))) + 8
-        n_steps = -(-n_steps // 256) * 256  # bucket: reuse the jit cache
+        # whole chunks of the device loop, bucketed to reuse the jit cache
+        n_steps = -(-n_steps // SCAN_CHUNK) * SCAN_CHUNK
         if n_steps > max_steps:
             raise ValueError(
                 f"execute_configs: bound of {n_steps} steps exceeds max_steps="
@@ -601,10 +664,12 @@ def execute_configs(
             edges = edges + wan_off[:, None]
 
         # steps each lane ran to its last completion (t_last is the end of
-        # that step, (i + 1) * dt), against the steps the scan ran them
-        tracing.count("repro.execute.lane_steps",
-                      int(np.rint(t_last / dt[:, None]).sum()))
-        tracing.count("repro.execute.scan_lane_steps", m * s * n_steps)
+        # that step, (i + 1) * dt), against the steps the scan ran them:
+        # whole chunks, up to the one holding the last lane's last one
+        lane_steps = np.rint(t_last / dt[:, None])
+        ran = SCAN_CHUNK * int(math.ceil(lane_steps.max() / SCAN_CHUNK))
+        tracing.count("repro.execute.lane_steps", int(lane_steps.sum()))
+        tracing.count("repro.execute.scan_lane_steps", m * s * ran)
 
         # float64 sums, one config row at a time to bound host memory
         lat_sum = np.stack([np.where(fin[i], lat[i].astype(np.float64), 0.0)

@@ -8,16 +8,21 @@ disjoint from every reference run below.  These tests pin that promise
 for ALL registered executables - the list comes from the registry via
 the ``executable_variant`` fixture (tests/conftest.py), so a newly
 registered variant inherits the cross-plane suite with zero edits here -
-plus the grid acceptance shape, the quorum-grid acceptor parity, and the
+plus the grid acceptance shape, the quorum-grid acceptor parity, the
 leader-crash replay whose recovery dip must match the transient plane's
-prediction.
+prediction, and the device loop that stops once every lane has drained.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.core.batched_execution as bx
+from repro.core import tracing
 from repro.core.api import (
     MIXED_50_50,
     WRITE_ONLY,
+    ShardingSpec,
     Workload,
     register_variant,
     temporary_variants,
@@ -203,6 +208,176 @@ def test_quorum_grid_sweep_acceptor_parity(mix):
         assert acc[1] < acc[2], acc
     else:
         assert abs(acc[1] - acc[2]) <= 1e-9, acc
+
+
+# ---------------------------------------------------------------------------
+# The device loop stops at the first chunk boundary after the drain
+# ---------------------------------------------------------------------------
+
+LOOP_GRID = [{"variant": "compartmentalized", "n_proxy_leaders": 2},
+             {"variant": "compartmentalized", "n_proxy_leaders": 3,
+              "n_replicas": 3},
+             {"variant": "multipaxos"}]
+LOOP_CASES = {
+    "deterministic": dict(configs=LOOP_GRID, workload=MIXED_50_50),
+    "exponential": dict(configs=LOOP_GRID, workload=MIXED_50_50,
+                        exponential_service=True),
+    # the third shard's weight is 0: a lane with no ops, drained at step 0
+    "sharded_zero_budget": dict(
+        configs=LOOP_GRID[:2], workload=Workload.read_mix(0.6),
+        sharding=ShardingSpec(3, weights=(0.6, 0.4, 0.0))),
+}
+
+
+@pytest.fixture
+def fresh_programs():
+    """Programs traced with a module function replaced are not reused."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _full_bound_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
+                     n_steps, n_clients, exponential):
+    """The same lane's step function under one ``lax.scan`` over every
+    step of the bound."""
+    state0, step, draws = bx._exec_lane(d_w, d_r, entry, nxt, cls_stream,
+                                        budget, dt, key, n_steps, n_clients,
+                                        exponential)
+    xs = (jnp.arange(n_steps, dtype=jnp.int32), draws)
+    state, (fin, lat) = jax.lax.scan(step, state0, xs)
+    return (fin, lat) + tuple(state[6:9])
+
+
+def _stalled_lane(real):
+    """Each step drains a billionth of the work: no lane completes an op
+    within the bound."""
+    def stalled(d_w, d_r, entry, nxt, cls_stream, budget, dt, *rest):
+        return real(d_w, d_r, entry, nxt, cls_stream, budget, dt * 1e-9,
+                    *rest)
+    return stalled
+
+
+def _execute_recorded(monkeypatch, configs, outs=None, **kwargs):
+    """execute_configs, and the device outputs of its one scan call."""
+    outs = [] if outs is None else outs
+    real = bx._execute_batch
+
+    def recorded(*args, **kw):
+        out = real(*args, **kw)
+        outs.append(jax.device_get(out))
+        return out
+    monkeypatch.setattr(bx, "_execute_batch", recorded)
+    try:
+        # 96 ops over 4 clients: lanes drain in the second or the third
+        # chunk, each run several chunks short of its bound
+        res = execute_configs(configs, n_commands=96, seeds=3, n_clients=4,
+                              probe_n=12, **kwargs)
+    finally:
+        monkeypatch.setattr(bx, "_execute_batch", real)
+    return res, outs[0], tracing.recent("repro.execute", 1)[0].counts
+
+
+def _steps_run(counts, res):
+    lanes = len(res) * len(res.seeds)
+    return counts["repro.execute.scan_lane_steps"] // lanes
+
+
+def _steps_on_device(lat):
+    """Steps the device ran, from its samples: on such a step every client
+    has a positive sample time (the step's end less its op's start),
+    whether or not it completed; the steps it never ran stay zero."""
+    return np.flatnonzero(np.any(lat != 0, axis=(0, 1, 3)))
+
+
+@pytest.mark.parametrize("case", sorted(LOOP_CASES))
+def test_drain_loop_matches_full_bound_scan(monkeypatch, fresh_programs,
+                                            case):
+    """The loop that stops at the drain computes what one scan over the
+    whole bound computes: the same samples on the steps it ran, no
+    completion after them, and bit-identical counts, makespans,
+    histograms and latency means."""
+    kwargs = dict(LOOP_CASES[case])
+    configs = kwargs.pop("configs")
+    res, out, counts = _execute_recorded(monkeypatch, configs, **kwargs)
+    monkeypatch.setattr(bx, "_one_exec_lane", _full_bound_lane)
+    jax.clear_caches()
+    ref, ref_out, _ = _execute_recorded(monkeypatch, configs, **kwargs)
+    ran = _steps_run(counts, res)
+    assert 0 < ran < res.n_steps == ref.n_steps
+    assert np.array_equal(_steps_on_device(out[1]), np.arange(ran))
+    assert np.array_equal(_steps_on_device(ref_out[1]),
+                          np.arange(ref.n_steps))
+    if case == "sharded_zero_budget":
+        assert np.any(res.lane_commands == 0)
+    fin, lat = out[:2]
+    ref_fin, ref_lat = ref_out[:2]
+    assert np.array_equal(fin[:, :, :ran], ref_fin[:, :, :ran])
+    assert not fin[:, :, ran:].any() and not ref_fin[:, :, ran:].any()
+    assert np.array_equal(lat[fin], ref_lat[ref_fin])
+    for got, want in zip(out[2:], ref_out[2:]):       # done_w, done_r, t_last
+        assert np.array_equal(got, want)
+    for field in ("completed", "n_writes", "throughput", "hist",
+                  "latency_mean", "latency_p50", "latency_p99", "bin_edges"):
+        assert np.array_equal(getattr(res, field), getattr(ref, field)), field
+
+
+@pytest.mark.parametrize("case", ["drains", "stalls"])
+def test_drain_loop_stops_at_the_chunk_after_the_last_lane(
+        monkeypatch, fresh_programs, case):
+    """The device runs whole chunks up to the one that holds the last
+    lane's last completion; a lane that cannot drain keeps the loop
+    running to the bound, and the guard refuses the result."""
+    kwargs = dict(LOOP_CASES["sharded_zero_budget"])
+    configs = kwargs.pop("configs")
+    if case == "stalls":
+        monkeypatch.setattr(bx, "_exec_lane", _stalled_lane(bx._exec_lane))
+        outs = []
+        with pytest.raises(RuntimeError, match="drained"):
+            _execute_recorded(monkeypatch, configs, outs, **kwargs)
+        fin, lat = outs[0][:2]
+        assert not fin.any()
+        assert np.array_equal(_steps_on_device(lat),
+                              np.arange(lat.shape[2]))
+        return
+    res, out, counts = _execute_recorded(monkeypatch, configs, **kwargs)
+    lane_steps = np.rint(out[4] / res.dt[:, None])        # t_last / dt
+    want = bx.SCAN_CHUNK * int(np.ceil(lane_steps.max() / bx.SCAN_CHUNK))
+    assert want < res.n_steps
+    assert np.array_equal(_steps_on_device(out[1]), np.arange(want))
+    assert _steps_run(counts, res) == want
+    assert counts["repro.execute.lane_steps"] == int(lane_steps.sum())
+
+
+def _outer_loops(jaxpr):
+    """The ``while`` equations of a jaxpr and of the calls in it, not
+    those nested inside another loop."""
+    loops = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "while":
+            loops.append(e)
+        elif "jaxpr" in e.params:                   # a jitted call
+            loops += _outer_loops(e.params["jaxpr"].jaxpr)
+    return loops
+
+
+def test_drain_loop_is_one_loop_over_the_batch():
+    """The loop's predicate is one value for the whole batch.  A predicate
+    per lane would make the vmapped loop select, chunk by chunk, between
+    the old and the new sample buffers of each lane, holding both."""
+    m, s, n, k, n_steps = 3, 2, 4, 15, 2 * bx.SCAN_CHUNK
+    sds = jax.ShapeDtypeStruct
+    f32, i32 = jnp.float32, jnp.int32
+    args = (sds((m, k), f32), sds((m, k), f32), sds((m,), i32),
+            sds((m, k), i32), sds((m, s, n, 6), i32), sds((m, n), i32),
+            sds((m,), f32), sds((s,), i32))
+    jaxpr = jax.make_jaxpr(lambda *a: bx._execute_batch(
+        *a, n_clients=n, n_steps=n_steps, exponential=False))(*args)
+    loops = _outer_loops(jaxpr.jaxpr)
+    assert len(loops) == 1
+    assert (m, s, n_steps, n) in {v.aval.shape for v in loops[0].outvars}
+    (pred,) = loops[0].params["cond_jaxpr"].jaxpr.outvars
+    assert pred.aval.shape == ()
 
 
 # ---------------------------------------------------------------------------

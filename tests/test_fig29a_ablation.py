@@ -19,6 +19,7 @@ from repro.core import (
     tracing,
     validate_batched,
 )
+from repro.core.batched_execution import SCAN_CHUNK
 
 
 def _majority(p, n):
@@ -119,9 +120,11 @@ def test_execute_counts_lane_and_scan_steps(read_fraction):
     res = execute_configs(configs, workload=Workload.read_mix(read_fraction),
                           n_commands=24, seeds=3, n_clients=4, probe_n=12)
     counts = tracing.recent("repro.execute", 1)[0].counts
-    steps = res.n_commands / res.throughput / res.dt[:, None]
-    assert counts["repro.execute.lane_steps"] == int(np.rint(steps).sum())
-    assert counts["repro.execute.scan_lane_steps"] == (
-        len(configs) * 3 * res.n_steps)
+    steps = np.rint(res.n_commands / res.throughput / res.dt[:, None])
+    assert counts["repro.execute.lane_steps"] == int(steps.sum())
+    # the device ran whole chunks up to the last lane's last completion
+    ran = SCAN_CHUNK * int(np.ceil(steps.max() / SCAN_CHUNK))
+    assert ran < res.n_steps
+    assert counts["repro.execute.scan_lane_steps"] == len(configs) * 3 * ran
     assert counts["repro.execute.lane_steps"] < (
         counts["repro.execute.scan_lane_steps"])

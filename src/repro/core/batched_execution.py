@@ -29,17 +29,17 @@ Cross-plane agreement with ``run_variant`` at different sizes and seeds
 (within each :class:`~repro.core.api.ExecutableSpec`'s tolerances, exact
 on its ``exact_stations``) is pinned by ``tests/test_batched_execution``.
 
-The engine itself mirrors ``transient._one_lane``: stations are FIFO
-queues draining work at ``dt / d_k`` per step, with the service demand
-chosen per the *class of the command at the head* (writes traverse the
-write path's demands, reads the read path's), commands walking the active
-stations in canonical slot order.  Clients park once their op budget
-drains, so the run has a makespan - measured throughput is
+Each step is :func:`repro.core.token_scan.step`, the transient plane's
+own: stations are FIFO queues draining work at ``dt / d_k`` per step,
+commands walking the active stations in canonical slot order.  What this
+engine adds is the *class of the command at the head*, which picks each
+station's demand (writes traverse the write path's, reads the read
+path's), and op budgets: a client whose budget is spent parks (it
+heads no queue again), so the run has a makespan - measured throughput is
 ``n_commands / t_last`` - and the device loop stops once every lane has
-drained; every completion emits a latency sample;
-the samples are histogrammed post-scan by the Pallas
-:func:`repro.kernels.ops.latency_hist` kernel with the transient plane's
-binning, so p50/p99 read identically across planes.
+drained.  Every completion emits a latency sample, histogrammed post-scan
+by the Pallas :func:`repro.kernels.ops.latency_hist` kernel on the
+transient plane's bin edges, so p50/p99 read identically across planes.
 
 Entry points: :func:`run_variant_batched` (one config),
 :func:`execute_configs` (any config list, e.g. a sweep's),
@@ -58,13 +58,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import tracing
+from . import token_scan, tracing
 from .analytical import STATION_ORDER, calibrate_alpha
 from .api import Config, ShardingSpec, Workload, resolve_workload, variant_spec
 from .execution import StationParity, default_config, run_variant
 from .sharding import shard_weights, split_counts
 from .sweep import config_variant
-from .transient import _quantile_from_hist
 from ..kernels.ops import latency_hist
 
 __all__ = [
@@ -155,91 +154,43 @@ def _exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
     d_w/d_r: [K] per-class service seconds; nxt: [K] tandem routing;
     cls_stream: [N, L] int32 op classes per client; budget: [N].
     ``step(state, (i, draws[i])) -> (state, (fin[N], lat[N]))`` runs step
-    ``i``; ``state[6:9]`` are done_w, done_r and t_last."""
-    k = d_w.shape[0]
+    ``i``; ``state[:5]`` are the token-scan tokens, ``state[5]`` each
+    client's op index and ``state[6:9]`` done_w, done_r and t_last."""
     n_ops = cls_stream.shape[1]
-    if exponential:
-        draws = jax.random.exponential(key, (n_steps + 1, k))
-    else:
-        draws = jnp.ones((n_steps + 1, k))
-
-    finishes_at = nxt == k
-    arrive_at = jnp.where(finishes_at, entry, nxt)
-
-    alive0 = budget > 0
-    stage0 = jnp.where(alive0, entry, k).astype(jnp.int32)  # k = parked
-    rank0 = jnp.cumsum(alive0.astype(jnp.int32)) - 1
-    enter0 = jnp.zeros((n_clients,))
-    q0 = (jnp.zeros((k,), jnp.int32)
-          .at[entry].add(jnp.sum(alive0.astype(jnp.int32))))
-    work0 = jnp.zeros((k,)).at[entry].set(draws[0, entry])
+    ring, tokens0, draws = token_scan.start(entry, nxt, key, budget > 0,
+                                            n_steps, exponential)
 
     def step(state, xs):
-        stage, rank, enter_t, op_i, q, work, done_w, done_r, t_last = state
+        tokens, (op_i, done_w, done_r, t_last) = state[:5], state[5:]
+        stage, rank = tokens[:2]
         i, draw_i = xs
-        t_end = (i + 1).astype(work.dtype) * dt
+        t_end = (i + 1).astype(tokens[4].dtype) * dt
 
-        # the phases of transient._one_lane's step; the latency samples
-        # are binned after the scan (no "bin" phase here)
         with jax.named_scope("execute.window"):
             cls_cur = jnp.take_along_axis(
                 cls_stream, jnp.clip(op_i, 0, n_ops - 1)[:, None],
                 axis=1)[:, 0]
             # the head command's class picks each station's service demand
-            # (parked clients sit at stage == k and scatter out of bounds)
-            head_cls = (jnp.zeros((k,), jnp.int32)
+            # (a parked client, at rank -1, heads no queue)
+            head_cls = (jnp.zeros_like(d_w, jnp.int32)
                         .at[stage].add(jnp.where(rank == 0, cls_cur, 0),
                                        mode="drop"))
-            d_now = jnp.where(head_cls > 0, d_w, d_r)
-            # a zero demand for the head's class (a read at the leader)
-            # drains instantly - still one completion per step, like
-            # transient.py
-            rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
+            rate = token_scan.drain_rate(jnp.where(head_cls > 0, d_w, d_r),
+                                         dt)
 
-        with jax.named_scope("execute.drain"):
-            busy = q > 0
-            work = jnp.where(busy, work - rate, work)
-            complete = busy & (work <= 0.0)                    # [K]
+        # a client whose op is its last parks once it completes
+        tokens, fin, lat = token_scan.step(tokens, rate, draw_i, t_end,
+                                           op_i + 1 >= budget, ring)
+        done_w = done_w + jnp.sum((fin & (cls_cur == 1)).astype(jnp.int32))
+        done_r = done_r + jnp.sum((fin & (cls_cur == 0)).astype(jnp.int32))
+        t_last = jnp.where(jnp.any(fin), t_end, t_last)
+        op_i = op_i + fin.astype(jnp.int32)
+        return (*tokens, op_i, done_w, done_r, t_last), (fin, lat)
 
-            alive = stage < k
-            stage_c = jnp.clip(stage, 0, k - 1)
-            dep_here = alive & complete[stage_c]               # [N]
-            moving = dep_here & (rank == 0)
-            fin = moving & finishes_at[stage_c]                # op done
-            lat = t_end - enter_t
-            done_w = done_w + jnp.sum(
-                (fin & (cls_cur == 1)).astype(jnp.int32))
-            done_r = done_r + jnp.sum(
-                (fin & (cls_cur == 0)).astype(jnp.int32))
-            t_last = jnp.where(jnp.any(fin), t_end, t_last)
-
-        with jax.named_scope("execute.route"):
-            op_next = op_i + fin.astype(jnp.int32)
-            more = op_next < budget
-            enters = moving & (~fin | more)  # next hop, or next op; or park
-            dest = arrive_at[stage_c]
-            q_dep = q - complete.astype(q.dtype)
-            stage_new = jnp.where(moving, jnp.where(enters, dest, k), stage)
-            enter_new = jnp.where(fin, t_end, enter_t)
-            rank_new = jnp.where(
-                moving, q_dep[dest],
-                rank - (dep_here & (rank > 0)).astype(rank.dtype))
-            arrivals = (jnp.zeros_like(q)
-                        .at[jnp.where(enters, dest, k)]
-                        .add(1, mode="drop"))
-            q_new = q_dep + arrivals
-            fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
-            work_new = jnp.where(
-                fresh, draw_i + jnp.where(complete, work, 0.0), work)
-
-        return ((stage_new, rank_new, enter_new, op_next, q_new, work_new,
-                 done_w, done_r, t_last), (fin, lat))
-
-    state0 = (stage0, rank0, enter0,
-              jnp.zeros((n_clients,), jnp.int32), q0, work0,
+    state0 = (*tokens0, jnp.zeros((n_clients,), jnp.int32),
               jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
               jnp.asarray(0.0))
-    return state0, step, draws[1:]
+    return state0, step, draws
 
 
 def _one_exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
@@ -286,7 +237,7 @@ def _one_exec_lane(d_w, d_r, entry, nxt, cls_stream, budget, dt, key,
              jnp.zeros((n_steps, n_clients)))
     _, (state_f, fin, lat) = jax.lax.while_loop(
         undrained, run_chunk, (jnp.asarray(0, jnp.int32), lane0))
-    _, _, _, _, _, _, done_w, done_r, t_last = state_f
+    done_w, done_r, t_last = state_f[6:9]
     return fin, lat, done_w, done_r, t_last
 
 
@@ -314,21 +265,6 @@ def _execute_batch(d_w, d_r, entry, nxt, cls, budget, dt, seeds,
 
     return jax.vmap(per_config, axis_name=LANE_AXES[0])(
         d_w, d_r, entry, nxt, cls, budget, dt, m_ids)
-
-
-def _routing(active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Tandem routing over active stations (transient.py's convention):
-    entry[M], next_station[M, K] with K = completion."""
-    m, k = active.shape
-    entry = np.zeros(m, dtype=np.int32)
-    nxt = np.full((m, k), k, dtype=np.int32)
-    for i in range(m):
-        idx = np.nonzero(active[i])[0]
-        if idx.size == 0:
-            raise ValueError(f"config row {i} has no active station")
-        entry[i] = idx[0]
-        nxt[i, idx[:-1]] = idx[1:]
-    return entry, nxt
 
 
 # ---------------------------------------------------------------------------
@@ -600,7 +536,7 @@ def execute_configs(
             cfg_r[rows] = bool(n_writes[rows].sum() < int(lane_n[rows].sum()))
         active = ((cfg_w[:, None] & (d_w > 0))
                   | (cfg_r[:, None] & (d_r > 0)))               # [M, K]
-        entry, nxt = _routing(active)
+        entry, nxt = token_scan.routing(active)
         dt = blend.max(axis=1) / oversample
         if np.any(dt <= 0):
             raise ValueError("a config row has zero effective demand")
@@ -619,11 +555,8 @@ def execute_configs(
                 f"execute_configs: bound of {n_steps} steps exceeds max_steps="
                 f"{max_steps}; raise max_steps or shrink the grid")
 
-        rtt = np.maximum((blend * active).sum(axis=1), 1e-12)
-        lo = rtt * 0.5
-        hi = np.maximum(n_steps * dt, lo * 10.0)
-        ratio = (hi / lo) ** (1.0 / n_bins)
-        edges = lo[:, None] * ratio[:, None] ** np.arange(n_bins + 1)[None, :]
+        edges = token_scan.bin_edges((blend * active).sum(axis=1), n_steps,
+                                     dt, n_bins)
 
     with tracing.span("repro.execute.dispatch"):
         out = _execute_batch(
@@ -693,8 +626,8 @@ def execute_configs(
             cost_read=cost_r,
             throughput=lane_n[:, None] / np.maximum(t_last, 1e-30),
             latency_mean=lat_sum / np.maximum(done, 1) + wan_off[:, None],
-            latency_p50=_quantile_from_hist(hist, edges, 0.50),
-            latency_p99=_quantile_from_hist(hist, edges, 0.99),
+            latency_p50=token_scan.quantile_from_hist(hist, edges, 0.50),
+            latency_p99=token_scan.quantile_from_hist(hist, edges, 0.99),
             completed=done.astype(np.float64),
             hist=hist,
             bin_edges=edges,

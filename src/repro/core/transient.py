@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import tracing
+from . import token_scan, tracing
 from .analytical import (
     STATION_INDEX,
     STATION_ORDER,
@@ -462,72 +462,34 @@ def region_partition_schedule(
 # ---------------------------------------------------------------------------
 
 
-def _routing(active: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Per-deployment tandem routing over active stations.
-
-    active: [M, K] bool.  Returns (entry[M], next_station[M, K]) where
-    ``next_station[m, k] == K`` marks command completion after station k
-    (inactive rows point to K too; they never host commands)."""
-    m, k = active.shape
-    entry = np.zeros(m, dtype=np.int32)
-    nxt = np.full((m, k), k, dtype=np.int32)
-    for i in range(m):
-        idx = np.nonzero(active[i])[0]
-        if idx.size == 0:
-            raise ValueError(f"deployment row {i} has no active station")
-        entry[i] = idx[0]
-        nxt[i, idx[:-1]] = idx[1:]
-    return entry, nxt
-
-
 def _one_lane(demands_w, step_bounds, dt, entry, nxt, bin_edges, key,
               n_clients: int, n_steps: int, warmup_steps: int,
               n_bins: int, exponential: bool):
     """Simulate one (deployment, seed) lane.  demands_w: [W, K] seconds;
     dt/entry scalars; nxt: [K]; bin_edges: [n_bins + 1]."""
     n_windows, k = demands_w.shape
-    if exponential:
-        draws = jax.random.exponential(key, (n_steps + 1, k))
-    else:
-        draws = jnp.ones((n_steps + 1, k))
-
-    finishes_at = nxt == k                     # station k completes commands
-    arrive_at = jnp.where(finishes_at, entry, nxt)   # [K] ring routing
-
-    stage0 = jnp.full((n_clients,), entry, dtype=jnp.int32)
-    rank0 = jnp.arange(n_clients, dtype=jnp.int32)
-    enter0 = jnp.zeros((n_clients,))
-    q0 = jnp.zeros((k,), jnp.int32).at[entry].add(n_clients)
-    work0 = jnp.zeros((k,)).at[entry].set(draws[0, entry])
+    ring, tokens0, draws = token_scan.start(
+        entry, nxt, key, jnp.ones((n_clients,), bool), n_steps, exponential)
 
     def step(state, xs):
-        stage, rank, enter_t, q, work, done, lat_sum, hist, qsum = state
+        tokens, (done, lat_sum, hist, qsum) = state[:5], state[5:]
         i, draw_i = xs
-        t_end = (i + 1).astype(work.dtype) * dt
+        t_end = (i + 1).astype(tokens[4].dtype) * dt
 
         with jax.named_scope("transient.window"):
             w = jnp.searchsorted(step_bounds, i, side="right") - 1
-            d_now = demands_w[w]                               # [K]
-            # a window may zero an active station's demand ("free"
-            # service): drain instantly rather than stall (still capped
-            # at one completion per step, i.e. 1/dt per station)
-            rate = jnp.where(d_now > 0, dt / jnp.maximum(d_now, 1e-30), 1e30)
+            rate = token_scan.drain_rate(demands_w[w], dt)
 
-        with jax.named_scope("transient.drain"):
-            busy = q > 0
-            work = jnp.where(busy, work - rate, work)
-            complete = busy & (work <= 0.0)                    # [K]
+        # a transient client never runs out of commands
+        tokens, fin, lat = token_scan.step(tokens, rate, draw_i, t_end,
+                                           False, ring)
 
-            dep_here = complete[stage]                         # [N]
-            moving = dep_here & (rank == 0)
-            fin = moving & finishes_at[stage]                  # command done
-            lat = t_end - enter_t
+        with jax.named_scope("transient.bin"):
             rec = fin & (i >= warmup_steps)
             n_rec = jnp.sum(rec)
             lat_fin = jnp.sum(jnp.where(rec, lat, 0.0))
             done = done + n_rec
             lat_sum = lat_sum + lat_fin
-        with jax.named_scope("transient.bin"):
             # at most one completion per lane-step: the routing is a
             # tandem, so only its last station completes commands, and a
             # FIFO station departs only its rank-0 client.  So lat_fin is
@@ -540,41 +502,23 @@ def _one_lane(demands_w, step_bounds, dt, entry, nxt, bin_edges, key,
                          0, n_bins - 1)
             hist = hist + jnp.where(jnp.arange(n_bins) == b, n_rec, 0)
 
-        with jax.named_scope("transient.route"):
-            dest = arrive_at[stage]                            # [N]
-            q_dep = q - complete.astype(q.dtype)
-            stage_new = jnp.where(moving, dest, stage)
-            enter_new = jnp.where(fin, t_end, enter_t)
-            rank_new = jnp.where(
-                moving, q_dep[dest],
-                rank - (dep_here & (rank > 0)).astype(rank.dtype))
-            arrivals = (jnp.zeros_like(q)
-                        .at[arrive_at].add(complete.astype(q.dtype)))
-            q_new = q_dep + arrivals
+        with jax.named_scope("transient.window"):
             # per-window queue-depth integral: the autoscale controller's
             # second signal (utilization says "how busy", queue depth
             # says "how far behind") - a [W, K] running sum is
             # ~n_steps/W cheaper to carry out of the scan than per-step
             # queue traces
-            qsum = qsum.at[w].add(q_new.astype(qsum.dtype))
-            # new head enters service: carry the completion residual on a
-            # busy server (unbiased long-run rate), fresh draw on an idle
-            # one
-            fresh = (complete & (q_new > 0)) | (~busy & (arrivals > 0))
-            work_new = jnp.where(
-                fresh, draw_i + jnp.where(complete, work, 0.0), work)
+            qsum = qsum.at[w].add(tokens[3].astype(qsum.dtype))
 
         out_flow = jnp.sum(fin).astype(jnp.int32)
-        return ((stage_new, rank_new, enter_new, q_new, work_new,
-                 done, lat_sum, hist, qsum), out_flow)
+        return (*tokens, done, lat_sum, hist, qsum), out_flow
 
-    state0 = (stage0, rank0, enter0, q0, work0,
+    state0 = (*tokens0,
               jnp.asarray(0, jnp.int32), jnp.asarray(0.0),
               jnp.zeros((n_bins,), jnp.int32),
               jnp.zeros((n_windows, k)))
-    xs = (jnp.arange(n_steps, dtype=jnp.int32), draws[1:])
-    (_, _, _, _, _, done, lat_sum, hist, qsum), flows = jax.lax.scan(
-        step, state0, xs)
+    xs = (jnp.arange(n_steps, dtype=jnp.int32), draws)
+    (*_, done, lat_sum, hist, qsum), flows = jax.lax.scan(step, state0, xs)
     return flows, done, lat_sum, hist, qsum
 
 
@@ -682,28 +626,6 @@ class TransientResult:
         return self.latency_p99.mean(axis=1)
 
 
-def _quantile_from_hist(hist: np.ndarray, edges: np.ndarray, q: float
-                        ) -> np.ndarray:
-    """hist: [M, S, B]; edges: [M, B+1] (log-spaced).  Returns [M, S]
-    latency at quantile q, log-interpolated inside the landing bin."""
-    cum = hist.cumsum(axis=2)
-    total = np.maximum(cum[:, :, -1], 1)
-    target = q * total
-    idx = np.minimum((cum < target[:, :, None]).sum(axis=2),
-                     hist.shape[2] - 1)
-    lo = np.take_along_axis(np.broadcast_to(edges[:, None, :-1], hist.shape),
-                            idx[:, :, None], axis=2)[:, :, 0]
-    hi = np.take_along_axis(np.broadcast_to(edges[:, None, 1:], hist.shape),
-                            idx[:, :, None], axis=2)[:, :, 0]
-    below = np.where(idx > 0,
-                     np.take_along_axis(cum, np.maximum(idx - 1, 0)[:, :, None],
-                                        axis=2)[:, :, 0], 0)
-    inbin = np.maximum(
-        np.take_along_axis(hist, idx[:, :, None], axis=2)[:, :, 0], 1)
-    frac = np.clip((target - below) / inbin, 0.0, 1.0)
-    return lo * (hi / lo) ** frac
-
-
 def _lower_transient(demands, step_bounds, n_steps, dt, oversample,
                      n_bins, seeds, warmup_frac):
     """:func:`simulate_transient`'s host lowering: the [W, M, K] demand
@@ -728,7 +650,7 @@ def _lower_transient(demands, step_bounds, n_steps, dt, oversample,
     _, m, k = d.shape
 
     active = d.max(axis=0) > 0                     # [M, K]
-    entry, nxt = _routing(active)
+    entry, nxt = token_scan.routing(active)
     if dt is None:
         # resolve the *fastest* window's bottleneck: each station completes
         # at most once per step, so dt must stay below the smallest
@@ -740,13 +662,9 @@ def _lower_transient(demands, step_bounds, n_steps, dt, oversample,
     if np.any(dt_arr <= 0):
         raise ValueError("dt must be positive (zero-demand window 0 row?)")
 
-    # log-spaced latency bins: from half the fastest window's zero-load
-    # round-trip up to the simulated horizon (the longest observable wait)
-    rtt = np.maximum((d * active[None]).sum(axis=2).min(axis=0), 1e-12)
-    lo = rtt * 0.5
-    hi = np.maximum(n_steps * dt_arr, lo * 10.0)
-    ratio = (hi / lo) ** (1.0 / n_bins)
-    bin_edges = lo[:, None] * ratio[:, None] ** np.arange(n_bins + 1)[None, :]
+    # latency bins from the fastest window's zero-load round trip
+    bin_edges = token_scan.bin_edges(
+        (d * active[None]).sum(axis=2).min(axis=0), n_steps, dt_arr, n_bins)
 
     if isinstance(seeds, (int, np.integer)):
         seeds_arr = np.arange(int(seeds), dtype=np.int32)
@@ -804,8 +722,8 @@ def simulate_transient(
             flows=flows,
             throughput=done / measured,
             latency_mean=lat_sum / np.maximum(done, 1),
-            latency_p50=_quantile_from_hist(hist, bin_edges, 0.50),
-            latency_p99=_quantile_from_hist(hist, bin_edges, 0.99),
+            latency_p50=token_scan.quantile_from_hist(hist, bin_edges, 0.50),
+            latency_p99=token_scan.quantile_from_hist(hist, bin_edges, 0.99),
             completed=done,
             hist=hist,
             bin_edges=bin_edges,

@@ -40,6 +40,9 @@ heads no queue again), so the run has a makespan - measured throughput is
 drained.  Every completion emits a latency sample, histogrammed post-scan
 by the Pallas :func:`repro.kernels.ops.latency_hist` kernel on the
 transient plane's bin edges, so p50/p99 read identically across planes.
+A second small device step compacts each client's completed samples
+(:func:`_completed_latencies`); the host pulls those, the histogram and
+the makespans, never the per-step samples of the whole step bound.
 
 Entry points: :func:`run_variant_batched` (one config),
 :func:`execute_configs` (any config list, e.g. a sweep's),
@@ -265,6 +268,45 @@ def _execute_batch(d_w, d_r, entry, nxt, cls, budget, dt, seeds,
 
     return jax.vmap(per_config, axis_name=LANE_AXES[0])(
         d_w, d_r, entry, nxt, cls, budget, dt, m_ids)
+
+
+@partial(jax.jit, static_argnames=("width",))
+def _completed_latencies(fin, lat, done, width: int):
+    """Each client's completed latency samples, in completion order.
+
+    fin/lat: [M, S, n_steps, N] as ``_execute_batch`` returns them, with
+    ``n_steps`` a multiple of ``SCAN_CHUNK``; done: [M, S] the lanes'
+    completions.  Returns samples[M, S, N, width]: slot ``j`` of a client
+    holds the ``lat`` of its ``j``-th completion, and 0 past its last one
+    (a client completes at most ``width`` ops).  The step axis is walked
+    one chunk at a time, carrying each client's completions so far, so no
+    array the size of ``fin`` is made, and the walk stops once every lane
+    has counted ``done``; each slot receives one value, so the samples are
+    exact copies."""
+    m, s, n_steps, n = fin.shape
+    slots = jnp.arange(width, dtype=jnp.int32)
+
+    def pending(carry):
+        c, count, _ = carry
+        return ((c < n_steps // SCAN_CHUNK)
+                & jnp.any(jnp.sum(count, axis=2) < done))
+
+    def chunk(carry):
+        c, count, samples = carry
+        fin_c = jax.lax.dynamic_slice_in_dim(fin, c * SCAN_CHUNK, SCAN_CHUNK,
+                                             axis=2)
+        lat_c = jax.lax.dynamic_slice_in_dim(lat, c * SCAN_CHUNK, SCAN_CHUNK,
+                                             axis=2)
+        hits = fin_c.astype(jnp.int32)
+        rank = count[:, :, None, :] + jnp.cumsum(hits, axis=2) - 1
+        put = fin_c[..., None] & (rank[..., None] == slots)
+        samples = samples + jnp.sum(jnp.where(put, lat_c[..., None], 0.0),
+                                    axis=2)
+        return c + 1, count + jnp.sum(hits, axis=2), samples
+
+    carry0 = (jnp.asarray(0, jnp.int32), jnp.zeros((m, s, n), jnp.int32),
+              jnp.zeros((m, s, n, width), lat.dtype))
+    return jax.lax.while_loop(pending, chunk, carry0)[2]
 
 
 # ---------------------------------------------------------------------------
@@ -578,14 +620,17 @@ def execute_configs(
             f"{done[tuple(short.T)].tolist()} of their op budgets in "
             f"{n_steps} steps - raise oversample margin or max_steps")
 
-    # histogram on the device, then pull the samples to the host once
+    # histogram and compact the completed samples on the device, then pull
+    # only those: [M, S, N, L], not every step of the bound
     s = seeds_arr.size
     with tracing.span("repro.execute.hist"):
         hist = latency_hist(lat.reshape(m * s, -1), fin.reshape(m * s, -1),
                             jnp.asarray(np.repeat(edges, s, axis=0)))
+        samples = _completed_latencies(fin, lat, done.astype(np.int32),
+                                       width=length)
     tracing.wait("repro.execute.wait", hist)
-    hist, lat, fin, t_last = tracing.pull(
-        "repro.execute.pull", hist, lat, fin, t_last)
+    hist, samples, t_last = tracing.pull(
+        "repro.execute.pull", hist, samples, t_last)
 
     with tracing.span("repro.execute.reduce"):
         hist = hist.reshape(m, s, n_bins)
@@ -604,9 +649,8 @@ def execute_configs(
         tracing.count("repro.execute.lane_steps", int(lane_steps.sum()))
         tracing.count("repro.execute.scan_lane_steps", m * s * ran)
 
-        # float64 sums, one config row at a time to bound host memory
-        lat_sum = np.stack([np.where(fin[i], lat[i].astype(np.float64), 0.0)
-                            .sum(axis=(1, 2)) for i in range(m)])
+        # float64 sums of the float32 samples (empty slots add 0)
+        lat_sum = samples.astype(np.float64).sum(axis=(2, 3))
         t_last = t_last.astype(np.float64)
 
         # completion-weighted blend of the probe-calibrated per-class costs:

@@ -22,6 +22,7 @@ from repro.core import tracing
 from repro.core.api import (
     MIXED_50_50,
     WRITE_ONLY,
+    GeoSpec,
     ShardingSpec,
     Workload,
     register_variant,
@@ -378,6 +379,114 @@ def test_drain_loop_is_one_loop_over_the_batch():
     assert (m, s, n_steps, n) in {v.aval.shape for v in loops[0].outvars}
     (pred,) = loops[0].params["cond_jaxpr"].jaxpr.outvars
     assert pred.aval.shape == ()
+
+
+# ---------------------------------------------------------------------------
+# The completed samples are compacted on the device before the pull
+# ---------------------------------------------------------------------------
+
+WIDTH = 6
+
+
+def _completion_steps(n_chunks, rng):
+    """Per (lane, client) the steps it completes on: lane (0, 0) holds the
+    edge cases, the other lanes draw 0 to WIDTH steps at random."""
+    c = bx.SCAN_CHUNK
+    n_steps = n_chunks * c
+    edge = [[],                                     # no completion
+            [5],                                    # one
+            [0, c - 1, c, 2 * c - 1, 2 * c, n_steps - 1],   # WIDTH, at edges
+            [c - 1, c],                             # adjacent chunks
+            list(range(10, 10 + WIDTH)),            # WIDTH in one chunk
+            sorted(rng.choice(n_steps, WIDTH - 2, replace=False))]
+    steps = np.empty((2, 2, len(edge)), dtype=object)
+    for idx in np.ndindex(steps.shape):
+        k = int(rng.integers(0, WIDTH + 1))
+        steps[idx] = (edge[idx[2]] if idx[:2] == (0, 0)
+                      else sorted(rng.choice(n_steps, k, replace=False)))
+    return steps, n_steps
+
+
+@pytest.mark.parametrize("n_chunks", [3, 5])
+@pytest.mark.parametrize("latencies", ["distinct", "equal"])
+def test_completed_latencies_are_each_clients_samples(n_chunks, latencies):
+    """Slot j of a client holds the latency of its j-th completion, in step
+    order, and 0 past its last; their float64 sum is the masked sum over
+    every step; the walk ends at the lanes' completion counts."""
+    rng = np.random.default_rng(n_chunks)
+    steps, n_steps = _completion_steps(n_chunks, rng)
+    m, s, n = steps.shape
+    fin = np.zeros((m, s, n_steps, n), dtype=bool)
+    for (mi, si, ci), at in np.ndenumerate(steps):
+        fin[mi, si, at, ci] = True
+    lat = (rng.uniform(0.5, 9.0, fin.shape) if latencies == "distinct"
+           else np.full(fin.shape, 0.25)).astype(np.float32)
+    done = fin.sum(axis=(2, 3), dtype=np.int32)
+    samples = np.asarray(bx._completed_latencies(
+        jnp.asarray(fin), jnp.asarray(lat), jnp.asarray(done), width=WIDTH))
+    assert samples.shape == (m, s, n, WIDTH)
+    assert samples.dtype == lat.dtype
+    for (mi, si, ci), at in np.ndenumerate(steps):
+        k = len(at)
+        assert np.array_equal(samples[mi, si, ci, :k], lat[mi, si, at, ci])
+        assert not samples[mi, si, ci, k:].any()
+    assert np.array_equal(
+        samples.astype(np.float64).sum(axis=(2, 3)),
+        np.where(fin, lat.astype(np.float64), 0.0).sum(axis=(2, 3)))
+    # the walk stops once every lane has counted its completions: none
+    # expected, no chunk read
+    assert not np.asarray(bx._completed_latencies(
+        jnp.asarray(fin), jnp.asarray(lat), jnp.zeros_like(done),
+        width=WIDTH)).any()
+
+
+GEO_3 = GeoSpec(regions=("us", "eu", "ap"),
+                rtt=((0, 8, 16), (8, 0, 12), (16, 12, 0)))
+REDUCE_CASES = {
+    "read_mix": dict(configs=LOOP_GRID, workload=Workload.read_mix(0.6)),
+    "sharded_uneven": LOOP_CASES["sharded_zero_budget"],
+    "geo": dict(configs=LOOP_GRID[:2], workload=MIXED_50_50, geo=GEO_3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REDUCE_CASES))
+def test_latency_mean_equals_the_reduce_over_every_step(monkeypatch, case):
+    """The mean from the compacted samples is, bit for bit, the float64
+    masked sum over every step of the scan's outputs, per completion,
+    plus the lane's WAN offset."""
+    kwargs = dict(REDUCE_CASES[case])
+    configs = kwargs.pop("configs")
+    res, out, _ = _execute_recorded(monkeypatch, configs, **kwargs)
+    fin, lat, done_w, done_r = out[:4]
+    lat_sum = np.where(fin, lat.astype(np.float64), 0.0).sum(axis=(2, 3))
+    done = done_w.astype(np.int64) + done_r
+    wan = np.zeros(len(res)) if res.wan_offset is None else res.wan_offset
+    if case == "geo":
+        assert np.all(wan > 0.0)
+    want = lat_sum / np.maximum(done, 1) + wan[:, None]
+    assert res.latency_mean.dtype == want.dtype
+    assert np.array_equal(res.latency_mean, want)
+
+
+def test_pull_does_not_scale_with_the_step_bound():
+    """An answer pulls done_w, done_r, the histogram, each client's
+    completed samples and the makespans: the same bytes whatever the
+    step bound."""
+    pulled, bounds = [], []
+    for oversample in (4.0, 8.0):
+        res = execute_configs(LOOP_GRID[:2], workload=MIXED_50_50,
+                              n_commands=24, seeds=2, n_clients=4,
+                              probe_n=12, oversample=oversample)
+        m, s = res.completed.shape
+        width = -(-res.n_commands // res.n_clients)
+        assert tracing.recent("repro.execute", 1)[0].counts[
+            tracing.PULL_BYTES] == (m * s * 4 * 2 + res.hist.nbytes
+                                    + m * s * res.n_clients * width * 4
+                                    + m * s * 4)
+        pulled.append(tracing.recent("repro.execute", 1)[0].counts[
+            tracing.PULL_BYTES])
+        bounds.append(res.n_steps)
+    assert bounds[0] < bounds[1] and pulled[0] == pulled[1]
 
 
 # ---------------------------------------------------------------------------

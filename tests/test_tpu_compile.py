@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.analytical import STATION_ORDER
-from repro.core.batched_execution import _execute_batch
+from repro.core.batched_execution import _completed_latencies, _execute_batch
 from repro.core.sweep import SweepSpec
 from repro.core.transient import _transient_batch
 from repro.kernels.latency_hist import latency_hist
@@ -104,6 +104,22 @@ def test_execute_scan_fits_one_v5e(one_chip, smoke, mix):
     total = ma.output_size_in_bytes + ma.temp_size_in_bytes
     assert total < HBM_BYTES
     assert total < smoke.EXEC_BYTES_LIMIT
+
+
+@pytest.mark.parametrize("mix", ["write_only", "read_90"])
+def test_completed_latencies_need_no_sample_sized_buffer(one_chip, smoke,
+                                                         mix):
+    """The compaction of the execution grid's samples, chunk by chunk,
+    keeps no temporary the size of its ``fin`` input on the chip."""
+    m = SweepSpec(**smoke.EXEC_KNOBS).size()
+    s = smoke.EXEC_SEEDS[mix]
+    n = smoke.EXEC["n_clients"]
+    shape = (m, s, smoke.EXEC_STEPS[mix], n)
+    ma = _completed_latencies.lower(
+        _arg(shape, jnp.bool_, one_chip), _arg(shape, jnp.float32, one_chip),
+        _arg((m, s), jnp.int32, one_chip),
+        width=-(-smoke.EXEC["n_commands"] // n)).compile().memory_analysis()
+    assert ma.temp_size_in_bytes < m * s * smoke.EXEC_STEPS[mix] * n
 
 
 def test_transient_scan_fits_one_v5e(one_chip, smoke):

@@ -176,9 +176,9 @@ def test_simulate_transient_makes_one_root(grid):
 
 def _execute_bytes(res):
     m, s = res.completed.shape
-    samples = m * s * res.n_steps * res.n_clients
-    # lat float32 and fin bool per sample; done_w, done_r, t_last per lane
-    return res.hist.nbytes + samples * 5 + m * s * 4 * 3
+    width = -(-res.n_commands // res.n_clients)
+    # a float32 slot per op of each client; done_w, done_r, t_last per lane
+    return res.hist.nbytes + m * s * res.n_clients * width * 4 + m * s * 4 * 3
 
 
 @pytest.mark.parametrize("mix,runs", [(0.5, 2), (0.0, 1)])
